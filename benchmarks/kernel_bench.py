@@ -72,8 +72,26 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Record, timed, write_bench_json
-from repro.core.ranking import AUDIT_TOL
+from repro.core.ranking import AUDIT_TOL, PARITY_ATOL, PARITY_RTOL
 from repro.kernels import ref
+
+
+_PARITY_FIELDS = ("perm", "utility", "exposure", "compliant")
+
+
+def _assert_parity(got, want, msg, fields=_PARITY_FIELDS):
+    """core.ranking's kernel parity contract: perm / compliant exact,
+    float outputs within PARITY_RTOL / PARITY_ATOL. `want` is a
+    RankingOutput or a dict of its fields."""
+    for f in fields:
+        g = np.asarray(getattr(got, f))
+        w = np.asarray(want[f] if isinstance(want, dict) else getattr(want, f))
+        if f in ("perm", "compliant"):
+            np.testing.assert_array_equal(g, w, err_msg=f"{msg} broke on {f}")
+        else:
+            np.testing.assert_allclose(g, w, rtol=PARITY_RTOL,
+                                       atol=PARITY_ATOL,
+                                       err_msg=f"{msg} broke on {f}")
 
 
 def _rank_audit_problem(n, m1, K, m2):
@@ -476,11 +494,7 @@ def check_knn_quant() -> None:
                         X_, p_, u_, a_, b_, g_, m2))(X, u, a, b, gamma)
                 w = dict(zip(("vals", "perm", "utility", "exposure",
                               "compliant", "lam"), want))
-                for f in ("perm", "utility", "exposure", "compliant"):
-                    np.testing.assert_array_equal(
-                        np.asarray(getattr(got, f)), np.asarray(w[f]),
-                        err_msg=f"quant parity broke on {f} "
-                                f"({mode}, slab={slab})")
+                _assert_parity(got, w, f"quant parity ({mode}, slab={slab})")
                 np.testing.assert_allclose(
                     np.asarray(got.lam), np.asarray(w["lam"]),
                     rtol=2e-7, atol=2e-7,
@@ -624,16 +638,6 @@ def run(quick: bool = False):
                  "derived": {"hbm_ratio_oracle_over_kernel":
                              round(oracle_traffic / kernel_traffic, 3)}})
 
-    # embedding_bag: oracle materializes gathered rows
-    V, Dd, nb, bag = (1_000_000, 64, 4096, 32) if not quick else (
-        10_000, 64, 512, 32)
-    kt, ki = jax.random.split(key)
-    table = jax.random.normal(kt, (V, Dd))
-    idx = jax.random.randint(ki, (nb, bag), 0, V)
-    h = jax.jit(lambda t, i: ref.embedding_bag_ref(t, i))
-    us = timed(lambda: h(table, idx), iters=3)
-    rows.append({"name": f"embedding_bag/V={V}/bag={bag}", "us": us,
-                 "derived": {"hbm_ratio_oracle_over_kernel": 2.0}})
     return rows
 
 
@@ -698,10 +702,7 @@ def check_rank_audited() -> None:
             f"calls, expected exactly 1")
 
     want = rank_given_lambda(u, a, b, lam, gamma, m2=m2)
-    for field in ("perm", "utility", "exposure", "compliant"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
-            err_msg=f"rank+audit interpret parity broke on {field}")
+    _assert_parity(got, want, "rank+audit interpret parity")
     want_big = rank_given_lambda(
         u, a, b, lam, jnp.abs(jax.random.normal(ks[4], (n, 256))), m2=256)
     for field in ("perm", "utility", "exposure", "compliant"):
@@ -709,8 +710,8 @@ def check_rank_audited() -> None:
             np.asarray(getattr(big, field)),
             np.asarray(getattr(want_big, field)),
             err_msg=f"rank+audit XLA fallback parity broke on {field}")
-    print("# rank+audit health: kernel engaged, interpret parity bitwise, "
-          "fallback parity bitwise -> PASS")
+    print("# rank+audit health: kernel engaged, interpret parity within "
+          "the parity contract, fallback parity bitwise -> PASS")
 
 
 def check_predict_rank() -> None:
@@ -773,20 +774,8 @@ def check_predict_rank() -> None:
 
     for name, pred in families.items():
         want = rank_given_lambda(u, a, b, pred.predict(X), gamma, m2=m2)
-        for field in ("perm", "utility", "exposure", "compliant"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(got[name], field)),
-                np.asarray(getattr(want, field)),
-                err_msg=f"predict+rank parity broke on {field} [{name}]")
-        if name == "knn":
-            np.testing.assert_allclose(
-                np.asarray(got[name].lam), np.asarray(want.lam),
-                rtol=1e-5, atol=1e-6,
-                err_msg="fused KNN λ̂ drifted")
-        else:
-            np.testing.assert_array_equal(
-                np.asarray(got[name].lam), np.asarray(want.lam),
-                err_msg=f"λ̂ parity broke [{name}]")
+        _assert_parity(got[name], want, f"predict+rank parity [{name}]",
+                       fields=_PARITY_FIELDS + ("lam",))
 
     _, idx_w, util_w, expo_w, comp_w, _ = ref.predict_rank_audited_ref(
         X, families["linear"], u, a, b, gamma_big, 256)
@@ -795,9 +784,8 @@ def check_predict_rank() -> None:
         np.testing.assert_array_equal(
             np.asarray(getattr(big, field)), np.asarray(want_f),
             err_msg=f"predict+rank XLA fallback parity broke on {field}")
-    print("# predict+rank health: kernels engaged per family, affine "
-          "prologue bitwise, KNN λ̂ within tolerance, fallback parity "
-          "-> PASS")
+    print("# predict+rank health: kernels engaged per family, outputs "
+          "and λ̂ within the parity contract, fallback parity -> PASS")
 
 
 def check_knn_fused() -> None:
@@ -863,10 +851,7 @@ def check_knn_fused() -> None:
             np.asarray(getattr(got, field)), np.asarray(getattr(chain, field)),
             err_msg=f"single-grid vs two-kernel chain broke on {field}")
     want = rank_given_lambda(u, a, b, pred.predict(X), gamma, m2=m2)
-    for field in ("perm", "utility", "exposure", "compliant"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
-            err_msg=f"single-grid vs oracle broke on {field}")
+    _assert_parity(got, want, "single-grid vs oracle")
     np.testing.assert_allclose(
         np.asarray(got.lam), np.asarray(want.lam), rtol=1e-5, atol=1e-6,
         err_msg="single-grid λ̂ drifted from the predictor")

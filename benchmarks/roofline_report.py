@@ -37,7 +37,7 @@ LEVERS = {
                       "larger microbatch raises arithmetic intensity",
     ("lm", "collective"): "reduce FSDP gather volume (group layers, "
                           "bigger per-chip batch) or cut TP degree",
-    ("recsys", "memory"): "fuse lookup+pool (embedding_bag kernel); "
+    ("recsys", "memory"): "fuse lookup+pool into one gather-sum; "
                           "row-shard tables to cut gather footprint",
     ("recsys", "collective"): "distributed top-k (k per shard, not full "
                               "gather); batch-parallel lookups",

@@ -23,6 +23,9 @@ def main() -> None:
     which = set(args.only.split(",")) if args.only != "all" else {
         "fig2", "table23", "dual", "serve", "kernels", "roofline"}
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
     failures = []
 

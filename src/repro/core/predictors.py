@@ -154,7 +154,9 @@ def _idw_lambda(d2_top: Array, x2: Array, y2_sel: Array,
     w_inv = 1.0 / jnp.maximum(dist, 1e-12)
     w = jnp.where(any_exact, exact.astype(d2_top.dtype), w_inv)
     w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return jnp.einsum("bk,bkc->bc", w, lam_neighbors)
+    # multiply + reduce, not a batched einsum: Mosaic refuses batched
+    # dot_general, and the KNN kernels call this at their λ̂ flush
+    return jnp.sum(w[:, :, None] * lam_neighbors, axis=1)
 
 
 @partial(jax.jit, static_argnames=("k",))

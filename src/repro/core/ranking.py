@@ -50,6 +50,16 @@ EPS_GRID = tuple([0.0] + [i * 10.0 ** (-j) for j in range(4, 0, -1) for i in ran
 # every audit path (jnp, kernel flush, distributed merge).
 AUDIT_TOL = 1e-6
 
+# Parity contract between a Pallas kernel path and the XLA reference in
+# this module. perm and compliant match exactly. utility, exposure and
+# lam are float sums whose reduction order differs between a kernel's
+# VMEM flush and the jnp reductions here (and, compiled on a TPU,
+# between Mosaic and XLA), so they match to a few ulp, not bitwise:
+# |got - want| <= PARITY_ATOL + PARITY_RTOL * |want|, about 8 f32 ulp
+# at magnitude 1.
+PARITY_RTOL = 1e-6
+PARITY_ATOL = 1e-6
+
 
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)

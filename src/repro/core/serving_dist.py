@@ -21,19 +21,16 @@ per-bucket executables when constructed with ``executor='dist'`` and a
 mesh (repro.serving.engine._rank_fn): the engine's submission side
 dispatches them asynchronously like any other bucket executable, and
 nothing in this module blocks — the only host-side wait lives in the
-engine pipeline's materialization step (and in ``warmup``). shard_map
-/ set_mesh go through repro.distributed.compat (see its docstring for
-when those shims can be dropped).
+engine pipeline's materialization step (and in ``warmup``).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.predictors import knn_topk_scan
-from repro.distributed.compat import shard_map
+from repro.core.predictors import _idw_lambda, knn_topk_scan
 from repro.distributed.topk import distributed_top_k, gather_merge_top_k
 
 Array = jax.Array
@@ -76,17 +73,9 @@ def knn_predict_distributed(
         gidx = idx_l + jax.lax.axis_index(db_axis) * n_l
         neg_d2, idx, y2_sel = gather_merge_top_k(
             neg_v, gidx, k, db_axis, payload=y2_sel_l)
-        d2k = -neg_d2                                        # (B_l, k) asc
-        lam_nb = lam_all[idx]                                # (B_l, k, K)
-        scale2 = x2 + y2_sel + 1e-12
-        exact = d2k <= 1e-6 * scale2
-        any_exact = jnp.any(exact, axis=-1, keepdims=True)
-        w_inv = 1.0 / jnp.maximum(jnp.sqrt(d2k), 1e-12)
-        w = jnp.where(any_exact, exact.astype(d2k.dtype), w_inv)
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return jnp.einsum("bk,bkc->bc", w, lam_nb)
+        return _idw_lambda(-neg_d2, x2, y2_sel, lam_all[idx])
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None), P(db_axis, None), P()),
         out_specs=P(batch_axes, None),
@@ -111,7 +100,7 @@ def knn_predict_quant_distributed(
     shard runs the quantized slab sweep + exact f32 survivor re-score
     (core.predictors.knn_quant_scan) on its rows, so the values that
     cross the interconnect are already EXACT-on-x̃ — the k·shards
-    merge (gather_merge_top_k) and the inline IDW tail are untouched
+    merge (gather_merge_top_k) and the shared IDW tail are untouched
     from the f32 path, and the result matches the dense
     knn_predict_quant selection bitwise (each shard's exact local
     top-k is a superset of its contribution to the global top-k; ties
@@ -142,23 +131,30 @@ def knn_predict_quant_distributed(
         gidx = idx_l + jax.lax.axis_index(db_axis) * n_l
         neg_d2, idx, y2_sel = gather_merge_top_k(
             -d2_l, gidx, k, db_axis, payload=y2_sel_l)
-        d2k = -neg_d2                                        # (B_l, k) asc
-        lam_nb = lam_all[idx]                                # (B_l, k, K)
-        scale2 = x2 + y2_sel + 1e-12
-        exact = d2k <= 1e-6 * scale2
-        any_exact = jnp.any(exact, axis=-1, keepdims=True)
-        w_inv = 1.0 / jnp.maximum(jnp.sqrt(d2k), 1e-12)
-        w = jnp.where(any_exact, exact.astype(d2k.dtype), w_inv)
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-        return jnp.einsum("bk,bkc->bc", w, lam_nb)
+        return _idw_lambda(-neg_d2, x2, y2_sel, lam_all[idx])
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None), P(db_axis, None),
                   P(db_axis, None), P(db_axis, None), P()),
         out_specs=P(batch_axes, None),
         check_vma=False,
     )(X, X_q, q_scale, y2_q, lam_db)
+
+
+def rank_input_shardings(mesh: Mesh, *, item_axis: str = "model",
+                         batch_axes=("pod", "data")) -> dict:
+    """Where rank_distributed's per-request inputs live on `mesh`:
+    candidates (u (B, m1), a (B, K, m1)) split over `item_axis` and
+    requests over the batch axes; ``rows`` is any other (B, ...) input
+    (b, gamma, lam, covariates); ``replicated`` for shared state. A
+    caller that places host inputs with these puts each device's shard
+    straight on that device, with no copy to one chip first."""
+    batch_axes = tuple(ax for ax in batch_axes if ax in mesh.axis_names)
+    return {"u": NamedSharding(mesh, P(batch_axes, item_axis)),
+            "a": NamedSharding(mesh, P(batch_axes, None, item_axis)),
+            "rows": NamedSharding(mesh, P(batch_axes, None)),
+            "replicated": NamedSharding(mesh, P())}
 
 
 def rank_distributed(
@@ -212,7 +208,7 @@ def rank_distributed(
         return RankingOutput(perm=idx, utility=utility, exposure=exposure,
                              compliant=compliant, lam=lam_l)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, item_axis), a_spec, b_spec,
                   P(batch_axes, None), gamma_spec),

@@ -171,8 +171,7 @@ def use_mesh_rules(mesh: Mesh | None, rules: AxisRules | None):
     _ctx.state = (mesh, filter_rules(rules, mesh)) if (mesh and rules) else None
     try:
         if mesh is not None:
-            from repro.distributed.compat import set_mesh
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield
         else:
             yield

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 Array = jax.Array
 
@@ -130,7 +129,7 @@ def sharded_knn_topk(
         neg_v, idx = distributed_top_k(-d2, k, shard_axis)
         return -neg_v, idx
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(xq, xdb)
@@ -153,7 +152,7 @@ def sharded_score_topk(
     def body(s_l):
         return distributed_top_k(s_l, k, shard_axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(scores)

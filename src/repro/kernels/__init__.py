@@ -10,13 +10,14 @@
 #                   same executable as XLA matmuls
 #   knn_topk / knn_lambda — lambda-predictor KNN over the train-user
 #                   database (top-k pairs / fused λ̂ emission)
-#   embedding_bag — recsys sparse-lookup substrate
 # Each has a pure-jnp oracle in ref.py; ops.py wraps with padding +
-# XLA fallbacks. Validated with interpret=True on CPU (tests/test_kernels.py,
-# tests/test_rank_audited.py, tests/test_predict_rank.py).
+# XLA fallbacks. Their results are checked with interpret=True on CPU
+# (tests/test_kernels.py, tests/test_rank_audited.py,
+# tests/test_predict_rank.py); the main-path kernels are compiled for a
+# described v5e in tests/test_tpu_compile.py and served on a chip by
+# chip_smoke.py.
 from repro.kernels import ref
 from repro.kernels.ops import (
-    embedding_bag,
     fused_rank,
     knn_lambda,
     knn_predict_kernel,

@@ -159,8 +159,8 @@ def topk_merge(
 #
 # Every helper below is shared verbatim by the Pallas kernels
 # (knn_topk.py), the XLA scan path (predictors.knn_quant_scan), and the
-# oracle (ref.py) — single-source math is what makes bitwise
-# kernel/oracle parity hold on both interpret and compiled backends.
+# oracle (ref.py) — single-source math leaves only the reduction order
+# to differ between kernel and oracle (core.ranking's parity contract).
 # ---------------------------------------------------------------------------
 
 QUANT_MODES = ("off", "bf16", "int8")
@@ -210,6 +210,7 @@ def quant_d2_tile(q: jnp.ndarray, db_q: jnp.ndarray, scale,
     f32 on the already-rounded values — no query error (bound 0)."""
     if mode == "int8":
         qi, sq = quantize_query(q)
+        # integer operands below 2^8 are exact in one bf16 MXU pass
         cross = jax.lax.dot_general(
             qi, db_q.astype(jnp.float32),
             (((1,), (1,)), ((), ())),
@@ -221,6 +222,7 @@ def quant_d2_tile(q: jnp.ndarray, db_q: jnp.ndarray, scale,
         xt = dequant_rows(db_q, scale)                   # (T, d) f32
         cross = jax.lax.dot_general(
             q, xt, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         q2 = jnp.sum(q * q, axis=-1, keepdims=True)
         d2 = q2 - 2.0 * cross + y2_row
@@ -236,7 +238,7 @@ def exact_rescore(q: jnp.ndarray, x_sel: jnp.ndarray,
     q (B, d), x_sel (B, d, k') dequantized survivor rows,
     y2_sel (B, k') their exact |x̃|^2 -> (B, k') f32."""
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)          # (B, 1)
-    cross = jnp.einsum("bd,bdk->bk", q, x_sel)           # (B, k')
+    cross = jnp.sum(q[:, :, None] * x_sel, axis=1)       # (B, k')
     return jnp.maximum(q2 - 2.0 * cross + y2_sel, 0.0)
 
 
@@ -248,7 +250,7 @@ def quant_d2_err(q: jnp.ndarray, x_sel: jnp.ndarray, *,
     with dequantized row x̃:  d2_quant - d2_exact = 2 (q - q̃) · x̃ —
     and at the flush the survivors' x̃ columns are VMEM-resident
     (x_sel (B, d, k')), so the error needs no bound at all: one small
-    einsum computes it exactly. The margin guard compares the quantized
+    multiply-and-sum computes it exactly. The margin guard compares the quantized
     k/(k+1) gap against the two boundary candidates' |err| sum — the
     precise condition under which query rounding could have swapped
     their order. bf16 mode rounds the db only (no query error) -> 0.
@@ -257,7 +259,7 @@ def quant_d2_err(q: jnp.ndarray, x_sel: jnp.ndarray, *,
         return jnp.zeros(x_sel.shape[:1] + x_sel.shape[-1:], jnp.float32)
     qi, sq = quantize_query(q)
     e = q - sq * qi                                          # (B, d)
-    return jnp.abs(2.0 * jnp.einsum("bd,bdk->bk", e, x_sel))
+    return jnp.abs(2.0 * jnp.sum(e[:, :, None] * x_sel, axis=1))
 
 
 def bottomk_rerank(d2: jnp.ndarray, gidx: jnp.ndarray, k: int,

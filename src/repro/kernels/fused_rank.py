@@ -319,7 +319,8 @@ def _linear_rank_audited_kernel(
         # W = 0 degenerate case with the clamp off).
         x = x_ref[...].astype(jnp.float32)               # (Bn, d)
         w = w_ref[...].astype(jnp.float32)               # (K, d)
-        lam = jnp.dot(x, w.T) + c_ref[...].astype(jnp.float32)
+        lam = jnp.dot(x, w.T, precision=jax.lax.Precision.HIGHEST
+                      ) + c_ref[...].astype(jnp.float32)
         if relu:
             lam = jnp.maximum(lam, 0.0)
         lam_scr[...] = lam

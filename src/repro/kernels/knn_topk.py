@@ -92,7 +92,10 @@ def _knn_kernel(
     db = db_ref[...].astype(jnp.float32)                     # (Tn, D)
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)              # (Bq, 1)
     db2 = jnp.sum(db * db, axis=-1)                          # (Tn,)
-    cross = jnp.dot(q, db.T, preferred_element_type=jnp.float32)
+    # full f32 passes: the default TPU matmul rounds operands to bf16,
+    # which would move neighbours against the f32 reference
+    cross = jnp.dot(q, db.T, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.maximum(q2 - 2.0 * cross + db2[None, :], 0.0)   # (Bq, Tn)
 
     base = t * tile_n
@@ -169,7 +172,8 @@ def _db_slab_merge(
     bq = q.shape[0]
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)              # (Bq, 1)
     db2 = jnp.sum(db * db, axis=-1)                          # (Tn,)
-    cross = jnp.dot(q, db.T, preferred_element_type=jnp.float32)
+    cross = jnp.dot(q, db.T, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.maximum(q2 - 2.0 * cross + db2[None, :], 0.0)   # (Bq, Tn)
 
     base = slab * tile_n
@@ -275,6 +279,13 @@ def knn_lambda_pallas(
 # Quantized db sweep: low-precision slab distances + exact survivor re-score
 # ---------------------------------------------------------------------------
 
+# The per-slab dequant scales sit whole in SMEM as a flat (n_slabs,)
+# vector and each db step reads its own slab's scalar: a (1, 1) VMEM
+# block over the (n_slabs, 1) array breaks the TPU's (8, 128) block
+# tiling rule.
+_SCALES_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def _db_slab_merge_quant(
     slab, q_ref, dbq_ref, scale_ref, y2q_ref, lamdb_ref,
     run_v, run_i, run_lam, run_y2, run_xr,
@@ -290,7 +301,7 @@ def _db_slab_merge_quant(
     repaired by the exact re-score instead of lost."""
     q = q_ref[...].astype(jnp.float32)                       # (Bq, D)
     dbq = dbq_ref[...]                                       # (Tn, D) stored
-    scale = scale_ref[0, 0]                                  # slab scale
+    scale = scale_ref[slab]                                  # slab scale (SMEM)
     lamdb = lamdb_ref[...].astype(jnp.float32)               # (Tn, K)
     y2 = y2q_ref[...].astype(jnp.float32)[:, 0]              # (Tn,) exact |x̃|²
     bq, d_dim = q.shape
@@ -429,7 +440,7 @@ def knn_lambda_quant_pallas(
         in_specs=[
             pl.BlockSpec((tile_q, D), lambda b, t: (b, 0)),
             pl.BlockSpec((tile_n, D), lambda b, t: (t, 0)),
-            pl.BlockSpec((1, 1), lambda b, t: (t, 0)),
+            _SCALES_SMEM,
             pl.BlockSpec((tile_n, 1), lambda b, t: (t, 0)),
             pl.BlockSpec((tile_n, K), lambda b, t: (t, 0)),
         ],
@@ -449,7 +460,7 @@ def knn_lambda_quant_pallas(
             pltpu.VMEM((tile_q, D, k_keep), jnp.float32),
         ],
         interpret=interpret,
-    )(xq, xdb_q, q_scale, y2_q, lam_db)
+    )(xq, xdb_q, q_scale.reshape(n_slabs), y2_q, lam_db)
     return lam, guard
 
 
@@ -734,7 +745,7 @@ def knn_rank_audited_quant_pallas(
         in_specs=[
             pl.BlockSpec((tile_b, D), lambda bi, t: (bi, 0)),
             pl.BlockSpec((tile_n, D), db_map),
-            pl.BlockSpec((1, 1), db_map),
+            _SCALES_SMEM,
             pl.BlockSpec((tile_n, 1), db_map),
             pl.BlockSpec((tile_n, K), db_map),
             pl.BlockSpec((tile_b, K), lambda bi, t: (bi, 0)),
@@ -774,5 +785,5 @@ def knn_rank_audited_quant_pallas(
             pltpu.VMEM((tile_b, K, m2), jnp.float32),      # ra: a payload
         ],
         interpret=interpret,
-    )(xq, xdb_q, q_scale, y2_q, lam_db, b, gamma, u, a)
+    )(xq, xdb_q, q_scale.reshape(n_slabs), y2_q, lam_db, b, gamma, u, a)
     return vals, idx, util, expo, comp, lam, guard

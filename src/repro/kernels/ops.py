@@ -6,8 +6,10 @@ constraints don't hold (huge m2, CPU runtime without interpret), and
 return results in the oracle's exact format so callers can swap paths
 without code changes.
 
-On this container (CPU) the kernels run with interpret=True; on TPU the
-same call sites compile the real kernels (interpret=False default).
+interpret=None chooses from the default backend: Mosaic-compiled kernels
+on a TPU, Pallas interpret mode anywhere else (the CPU test suite).
+tests/test_tpu_compile.py compiles the main-path kernels for a described
+v5e, and chip_smoke.py serves through them on a real one.
 """
 
 from __future__ import annotations
@@ -27,7 +29,6 @@ from repro.kernels.common import (
     TILE_B,
     TILE_M,
 )
-from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.fused_rank import (
     MAX_KERNEL_M2,
     fused_rank_pallas,
@@ -583,37 +584,12 @@ def knn_predict_kernel(
 ) -> Array:
     """Kernel-backed twin of repro.core.predictors.knn_predict (same
     inverse-distance weighting and exact-match semantics)."""
+    from repro.core.predictors import _idw_lambda  # deferred: no cycle
+
     squeeze = X.ndim == 1
     Xq = jnp.atleast_2d(X)
     d2, idx = knn_topk(Xq, X_db, k=k, interpret=interpret)
-    dist = jnp.sqrt(d2)
     x2 = jnp.sum(Xq * Xq, axis=-1, keepdims=True)
     y2 = jnp.sum(X_db * X_db, axis=-1)[idx]
-    exact = d2 <= 1e-6 * (x2 + y2 + 1e-12)
-    any_exact = jnp.any(exact, axis=-1, keepdims=True)
-    w_inv = 1.0 / jnp.maximum(dist, 1e-12)
-    w = jnp.where(any_exact, exact.astype(d2.dtype), w_inv)
-    w = w / jnp.sum(w, axis=-1, keepdims=True)
-    out = jnp.einsum("bk,bkc->bc", w, lam_db[idx])
+    out = _idw_lambda(d2, x2, y2, lam_db[idx])
     return out[0] if squeeze else out
-
-
-# ---------------------------------------------------------------------------
-# embedding_bag
-# ---------------------------------------------------------------------------
-
-def embedding_bag(
-    table: Array, indices: Array, weights: Array | None = None, *,
-    use_kernel: bool = True, interpret: bool | None = None, tile_b: int = 8,
-):
-    """(n_bags, D) sum-mode bag. See ref.embedding_bag_ref."""
-    if not use_kernel:
-        return ref.embedding_bag_ref(table, indices, weights)
-    if interpret is None:
-        interpret = not _on_tpu()
-    n_bags = indices.shape[0]
-    idx_p = _pad_to(indices, 0, tile_b, -1)
-    w_p = None if weights is None else _pad_to(weights, 0, tile_b, 0.0)
-    out = embedding_bag_pallas(
-        table, idx_p, w_p, tile_b=tile_b, interpret=interpret)
-    return out[:n_bags]

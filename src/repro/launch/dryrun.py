@@ -2,12 +2,15 @@ import os
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above run BEFORE any other import (jax locks the device
-count at first init). Do NOT replicate them anywhere global — smoke
-tests and benchmarks must see the single real CPU device.
+The lines above run BEFORE any other import (jax locks the platform and
+the device count at first init). They pin this host-device lowering
+tool to the CPU, so it never takes an accelerator that another process
+holds. Do NOT replicate them anywhere global — smoke tests and
+benchmarks must see the single real device.
 
 Per cell:
   with mesh:

@@ -16,7 +16,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import logical_shard
 
 Array = jax.Array
@@ -542,7 +541,7 @@ def moe_apply_shmap(params, x: Array, cfg, mesh) -> tuple[Array, Array]:
     batch_spec = batch_axes if batch_axes else None
     w_spec = P("model", batch_spec, None)
     we = params["experts"]
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_spec, None, None), P(), w_spec, w_spec,
                   P("model", None, batch_spec)),

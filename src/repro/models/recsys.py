@@ -2,8 +2,7 @@
 
 The hot path for all four is the sparse embedding lookup. JAX has no
 native EmbeddingBag / CSR — `embedding_bag` below implements it with
-``jnp.take`` + ``jax.ops.segment_sum`` (and a Pallas kernel twin in
-repro.kernels.embedding_bag for the VMEM-tiled version). Embedding
+``jnp.take`` + ``jax.ops.segment_sum``. Embedding
 tables are row-sharded over the 'model' mesh axis (the tables ARE the
 model); the dense towers are small and replicated.
 

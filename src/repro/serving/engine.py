@@ -132,6 +132,7 @@ class RankResult:
     rung: int = 0                     # degradation rung served (0 = own)
     epoch: int = 0                    # predictor generation that served it
     lattice_epoch: int = 0            # bucket-lattice generation at dispatch
+    lam: np.ndarray | None = None     # (K,) shadow prices the ranking used
 
 
 @dataclass
@@ -315,9 +316,18 @@ class ServingEngine:
         self._predictors[tag] = _PredictorEntry(
             predictor=predictor, d_cov=int(d_cov), K=int(probe.shape[-1]))
         with self._swap_lock:
-            self._pred_state[tag] = jax.device_put(
+            self._pred_state[tag] = self._place_state(
                 predictor_state(predictor))
             self._pred_epoch[tag] = 0
+
+    def _place_state(self, state: dict) -> dict:
+        """Predictor state onto the device(s) its executables read it
+        from: the default device, or every device of the dist mesh."""
+        if self.executor != "dist":
+            return jax.device_put(state)
+        from repro.core.serving_dist import rank_input_shardings
+        return jax.device_put(state,
+                              rank_input_shardings(self.mesh)["replicated"])
 
     def set_degradation_ladder(self, tag: str, fallbacks) -> None:
         """Register `tag`'s degradation ladder: when admission predicts
@@ -438,7 +448,7 @@ class ServingEngine:
                     f"swap {tag!r}: non-finite values in leaf "
                     f"{jax.tree_util.keystr(path)} — poisoned state "
                     f"refused, serving stays on the live generation")
-        state = jax.device_put(state)     # phase 1: publish new buffers
+        state = self._place_state(state)  # phase 1: publish new buffers
         with self._swap_lock:             # phase 2: flip at batch boundary
             old_epoch = self._pred_epoch[tag]
             new_epoch = old_epoch + 1 if epoch is None else int(epoch)
@@ -706,12 +716,23 @@ class ServingEngine:
             kernel_launch_count(predictor, bucket.m2)
             if self.executor == "fused" else 0)
         rank = self._rank_fn(bucket)
+        # dist: host staging transfers straight into the mesh layout
+        # rank_distributed computes in, one shard per device
+        placed = {}
+        if self.executor == "dist":
+            from repro.core.serving_dist import rank_input_shardings
+            sh = rank_input_shardings(self.mesh)
+            rows = sh["rows"]
+            placed["in_shardings"] = (
+                (rows, rows, sh["u"], sh["a"], rows) if bucket.tag == LAM_TAG
+                else (sh["replicated"], rows, rows, sh["u"], sh["a"], rows))
         if bucket.tag == LAM_TAG:
 
             def fn(b, gamma, u, a, lam):
                 return rank(u, a, b, lam, gamma)
 
-            return jax.jit(fn, donate_argnums=(2, 3) if self.donate else ())
+            return jax.jit(fn, donate_argnums=(2, 3) if self.donate else (),
+                           **placed)
 
         # Predictor-tagged buckets take the predictor's ARRAY state as
         # argument 0 instead of closing over it: closed-over arrays are
@@ -735,7 +756,7 @@ class ServingEngine:
                 lam = jnp.pad(lam, ((0, 0), (0, pad_k)))
                 return rank(u, a, b, lam, gamma)
 
-            return jax.jit(fn, donate_argnums=donate)
+            return jax.jit(fn, donate_argnums=donate, **placed)
 
         # The single-sweep dispatcher (kernels.ops.predict_rank_audited
         # behind its stateful seam): predict + rank + audit lower to
@@ -823,15 +844,31 @@ class ServingEngine:
             return None
         return self._predictors[bucket.tag].d_cov
 
-    def _call(self, fn, bucket: Bucket, staged: dict,
-              state: dict | None = None) -> RankingOutput:
+    def _args(self, bucket: Bucket, staged: dict,
+              state: dict | None = None) -> tuple:
+        """A bucket executable's arguments, in its signature's order."""
         if bucket.tag == LAM_TAG:
-            return fn(staged["b"], staged["gamma"], staged["u"], staged["a"],
-                      staged["lam"])
+            return (staged["b"], staged["gamma"], staged["u"], staged["a"],
+                    staged["lam"])
         if state is None:                  # warmup path: no gen pinning
             state = self._pred_state[bucket.tag]
-        return fn(state, staged["b"], staged["gamma"], staged["u"],
-                  staged["a"], staged["X"])
+        return (state, staged["b"], staged["gamma"], staged["u"],
+                staged["a"], staged["X"])
+
+    def _call(self, fn, bucket: Bucket, staged: dict,
+              state: dict | None = None) -> RankingOutput:
+        return fn(*self._args(bucket, staged, state))
+
+    def compiled_executables(self) -> dict[str, jax.stages.Compiled]:
+        """The compiled program of every warmed bucket, by bucket name,
+        lowered from a phantom batch of the bucket's shapes. Its
+        ``as_text()`` is what the device runs (a Pallas kernel appears
+        as a ``tpu_custom_call``), its ``input_shardings`` where each
+        argument lives."""
+        return {bk.name: self._exec[bk].lower(*self._args(
+                    bk, assemble_batch([], bk, d_cov=self._dcov(bk))))
+                .compile()
+                for bk in sorted(self._warmed)}
 
     def jit_cache_sizes(self) -> dict[str, int]:
         """Per-bucket jit compile-cache sizes (1 = exactly the warmed
@@ -1153,7 +1190,8 @@ class ServingEngine:
             latency_ms=(pending.t_done - t_enq) * 1e3,
             wait_ms=(pending.t_launch - t_enq) * 1e3,
             deadline_hit=deadline_hit, rung=entry.rung,
-            epoch=pending.epoch, lattice_epoch=pending.lattice_epoch)
+            epoch=pending.epoch, lattice_epoch=pending.lattice_epoch,
+            lam=np.asarray(pending.out.lam[i, :req.b.shape[0]]))
 
     # -- convenience driver -------------------------------------------------
 

@@ -7,9 +7,11 @@
     the chain's own merge/flush bodies (_db_slab_merge, _idw_lambda_flush,
     _merge_scored_tile, _audit_flush);
   * the two-stage predictor.predict(X) -> rank_given_lambda oracle —
-    exact on perm/utility/exposure/compliant (score gaps dwarf the λ̂
-    perturbation on these problems), λ̂ to tight tolerance (per-slab vs
-    one-matmul distance accumulation differs in the last ulp).
+    exact on perm/compliant (score gaps dwarf the λ̂ perturbation on
+    these problems); utility/exposure within core.ranking's
+    PARITY_RTOL/ATOL (the kernel flush and the jnp audit reduce in a
+    different order), λ̂ to tight tolerance (per-slab vs one-matmul
+    distance accumulation differs in the last ulp).
 
 Plus the geometry battery the kernel's phased grid makes interesting:
 slab sizes that do and do not divide n_train, bucket-padded engine
@@ -23,13 +25,14 @@ import numpy as np
 import pytest
 
 from repro.core.predictors import KNNLambdaPredictor
-from repro.core.ranking import rank_given_lambda
+from repro.core.ranking import PARITY_ATOL, PARITY_RTOL, rank_given_lambda
 from repro.kernels import ops
 from repro.kernels.fused_rank import MAX_KERNEL_M2
 
 KEY = jax.random.key(29)
 
 FIELDS = ("perm", "utility", "exposure", "compliant")
+EXACT = ("perm", "compliant")
 N_TRAIN = 600
 
 
@@ -45,24 +48,32 @@ def _problem(n, m1, K, m2, d=12, n_train=N_TRAIN, k=5, salt=0):
     return u, a, b, gamma, X, KNNLambdaPredictor.fit(X_tr, lam_tr, k=k)
 
 
+def _assert_oracle(got, want, rows=slice(None), msg=""):
+    for field in FIELDS:
+        g = np.asarray(getattr(got, field))[rows]
+        w = np.asarray(getattr(want, field))[rows]
+        err = f"single-grid vs oracle broke on {field} {msg}"
+        if field in EXACT:
+            np.testing.assert_array_equal(g, w, err_msg=err)
+        else:
+            np.testing.assert_allclose(g, w, rtol=PARITY_RTOL,
+                                       atol=PARITY_ATOL, err_msg=err)
+
+
 def _assert_parity(got, chain, want, msg=""):
     for field in FIELDS + ("lam",):
         np.testing.assert_array_equal(
             np.asarray(getattr(got, field)),
             np.asarray(getattr(chain, field)),
             err_msg=f"single-grid vs chain broke on {field} {msg}")
-    for field in FIELDS:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, field)),
-            np.asarray(getattr(want, field)),
-            err_msg=f"single-grid vs oracle broke on {field} {msg}")
+    _assert_oracle(got, want, msg=msg)
 
 
 @pytest.mark.parametrize("tile_n", [200, 600, 160, 512])
 def test_single_grid_parity_across_slab_sizes(tile_n):
     """tile_n in {200, 600} divides n_train = 600; {160, 512} does not
     (the db pads with far-away rows). All four give the chain's answer
-    bitwise and the oracle's fields exactly."""
+    bitwise and the oracle's fields within the parity contract."""
     n, m1, K, m2 = 11, 700, 4, 16
     u, a, b, gamma, X, pred = _problem(n, m1, K, m2)
     got = ops.predict_rank_audited(X, pred, u, a, b, gamma, m2=m2,
@@ -108,7 +119,7 @@ def test_single_grid_bucket_padded_batch():
     """An engine-style micro-batch: phantom rows, NEG_FILL candidate
     padding, and a constraint tier WIDER than the predictor's output —
     the padded constraints must price at exactly 0.0 (zero lam_db
-    columns through the flush-step einsum), phantom rows must audit to
+    columns through the flush-step weighting), phantom rows must audit to
     zero utility and trivial compliance."""
     from repro.serving import Scenario, assemble_batch, bucket_for, make_request
 
@@ -131,11 +142,8 @@ def test_single_grid_bucket_padded_batch():
     lam = jnp.pad(pred.predict(X), ((0, 0), (0, bucket.K - K_pred)))
     want = rank_given_lambda(u, a, b, lam, gamma, m2=bucket.m2)
     n_real = len(reqs)
-    for field in FIELDS:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, field))[:n_real],
-            np.asarray(getattr(want, field))[:n_real],
-            err_msg=f"padded KNN micro-batch broke on {field}")
+    _assert_oracle(got, want, rows=slice(None, n_real),
+                   msg="(padded KNN micro-batch)")
     # the bucket-padded constraint columns price at exactly zero
     np.testing.assert_array_equal(np.asarray(got.lam)[:, K_pred:], 0.0)
     # phantom rows: zero gamma -> zero utility, trivially compliant
@@ -162,7 +170,8 @@ def test_exact_match_neighbour_inside_later_slab():
     np.testing.assert_allclose(np.asarray(got.lam[1]),
                                np.asarray(pred.lam_db[N_TRAIN - 1]),
                                rtol=1e-4, atol=1e-5)
-    # and the full output still matches the chain bitwise
+    # and the full output still matches the chain bitwise, the oracle
+    # within the parity contract
     chain = ops.predict_rank_audited(X, pred, u, a, b, gamma, m2=m2,
                                      interpret=True, tile_n=tile_n,
                                      knn_chain=True)

@@ -8,8 +8,9 @@ survivor re-score) vs its oracles, end to end:
     that do and do not divide n_train;
   * the full-RankingOutput contract: ops.predict_rank_audited on a
     quantized predictor vs the COMPILED f32 oracle — the parity target
-    the paper's serving path actually guarantees (perm / utility /
-    exposure / compliant bitwise, λ̂ to 1-ulp einsum-layout tolerance);
+    the paper's serving path actually guarantees (perm / compliant
+    exact, utility / exposure / λ̂ within core.ranking's
+    PARITY_RTOL/ATOL: the kernel flush reduces in another order);
   * adversarial near-ties planted inside the quantization error fire
     the margin guard (observability for forced fallbacks);
   * degenerate all-identical db rows: every distance ties, selection
@@ -41,6 +42,7 @@ from repro.core.predictors import (
     state_fields,
     with_state,
 )
+from repro.core.ranking import PARITY_ATOL, PARITY_RTOL
 from repro.kernels import ops, ref
 from repro.kernels.common import PAD_Y2, QUANT_EXTRA, dequant_rows
 from repro.kernels.knn_topk import knn_lambda_quant_pallas
@@ -147,9 +149,13 @@ def test_predict_rank_audited_quant_parity(mode):
         *t[:1], pred, *t[1:], m2))(X, u, a, b, gamma)
     w = dict(zip(("vals", "perm", "utility", "exposure", "compliant",
                   "lam"), want))
-    for f in FIELDS:
+    for f in ("perm", "compliant"):
         np.testing.assert_array_equal(
             np.asarray(getattr(got, f)), np.asarray(w[f]), err_msg=f)
+    for f in ("utility", "exposure"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(got, f)), np.asarray(w[f]),
+            rtol=PARITY_RTOL, atol=PARITY_ATOL, err_msg=f)
     np.testing.assert_allclose(np.asarray(got.lam), np.asarray(w["lam"]),
                                rtol=2e-7, atol=2e-7)
 

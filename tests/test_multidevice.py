@@ -20,7 +20,8 @@ _PROG = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_smoke_mesh
+    mesh = make_smoke_mesh()                      # (2, 4) data x model
 
     # ---- distributed top-k == global top-k --------------------------------
     from repro.distributed.topk import sharded_knn_topk, sharded_score_topk
@@ -53,8 +54,7 @@ _PROG = textwrap.dedent("""
     from repro.optim.compression import compressed_psum
     x = jax.random.normal(jax.random.fold_in(key, 3), (8, 128))
     x_sh = jax.device_put(x, NamedSharding(mesh, P("data", None)))
-    from repro.distributed.compat import shard_map
-    out = shard_map(
+    out = jax.shard_map(
         lambda xs: compressed_psum(xs, "data"),
         mesh=mesh, in_specs=P("data", None), out_specs=P("data", None),
         check_vma=False)(x_sh)
@@ -158,10 +158,10 @@ _PROG = textwrap.dedent("""
     specs = dict(mesh=mesh,
                  in_specs=(P("data", None), P("model", None), P()),
                  check_vma=False)
-    old_idx, old_y2, old_lam = shard_map(
+    old_idx, old_y2, old_lam = jax.shard_map(
         old_dense_body, out_specs=(P("data", None),) * 3, **specs)(
             X, X_db, lam_db)
-    new_idx, new_y2 = shard_map(
+    new_idx, new_y2 = jax.shard_map(
         new_selection_body, out_specs=(P("data", None),) * 2, **specs)(
             X, X_db, lam_db)
     np.testing.assert_array_equal(np.asarray(new_idx), np.asarray(old_idx))
@@ -249,6 +249,8 @@ _PROG = textwrap.dedent("""
 def test_multidevice_semantics():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # forced host devices: the child must never try to take an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _PROG], capture_output=True,
                        text=True, env=env, cwd=os.path.dirname(
                            os.path.dirname(os.path.abspath(__file__))),

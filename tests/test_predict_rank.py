@@ -2,16 +2,20 @@
 vs the two-stage oracle `predictor.predict(X)` -> `rank_given_lambda`,
 for all four predictor families.
 
-Parity contract (the dispatcher's docstring, asserted here):
-  * linear / mean — the affine prologue folded into the rank kernel is
-    BITWISE identical on the interpret path (same jnp.dot + max ops as
-    LinearLambdaPredictor.predict, executed per batch tile in VMEM);
-  * knn — the fused inverse-distance weighting agrees to tight
-    tolerance (per-tile vs one-matmul distance accumulation differs in
-    the last ulp); selection and audit outputs still agree exactly on
-    these fixed-seed problems (score gaps are orders of magnitude above
-    the λ̂ perturbation);
-  * mlp — λ̂ stays XLA inside the same executable: bitwise.
+Parity contract (core.ranking PARITY_RTOL / PARITY_ATOL, asserted
+here): perm and compliant exact; utility and exposure within the
+tolerance, since the kernel's flush and the jnp audit reduce in a
+different order. λ̂ per family:
+  * linear / mean — the affine prologue folded into the rank kernel
+    runs the same jnp.dot + max ops as LinearLambdaPredictor.predict;
+    the dot may still reduce in another order, so λ̂ is held to the
+    tolerance (the covariate-free mean stays bitwise);
+  * knn — the fused inverse-distance weighting agrees within the
+    tolerance (per-tile vs one-matmul distance accumulation); selection
+    still agrees exactly on these fixed-seed problems (score gaps are
+    orders of magnitude above the λ̂ perturbation);
+  * mlp — λ̂ stays XLA inside the same executable, within the
+    tolerance.
 
 Plus: bucket-padded micro-batches (phantom rows, padded K tier), the
 m2 = MAX_KERNEL_M2 edge, the m2 > MAX_KERNEL_M2 XLA fallback, and the
@@ -29,13 +33,14 @@ from repro.core.predictors import (
     MeanLambdaPredictor,
     MLPLambdaPredictor,
 )
-from repro.core.ranking import rank_given_lambda
+from repro.core.ranking import PARITY_ATOL, PARITY_RTOL, rank_given_lambda
 from repro.kernels import ops, ref
 from repro.kernels.fused_rank import MAX_KERNEL_M2
 
 KEY = jax.random.key(11)
 
 FIELDS = ("perm", "utility", "exposure", "compliant")
+EXACT = ("perm", "compliant")
 
 D_COV = 12
 
@@ -61,11 +66,16 @@ def _families(X_tr, lam_tr):
     }
 
 
-def _assert_fields_equal(got, want, pad_k=0, msg=""):
+def _assert_fields_equal(got, want, rows=slice(None), msg=""):
     for field in FIELDS:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
-            err_msg=f"predict+rank parity broke on {field} {msg}")
+        g = np.asarray(getattr(got, field))[rows]
+        w = np.asarray(getattr(want, field))[rows]
+        err = f"predict+rank parity broke on {field} {msg}"
+        if field in EXACT:
+            np.testing.assert_array_equal(g, w, err_msg=err)
+        else:
+            np.testing.assert_allclose(g, w, rtol=PARITY_RTOL,
+                                       atol=PARITY_ATOL, err_msg=err)
 
 
 @pytest.mark.parametrize("n,m1,K,m2", [
@@ -80,16 +90,12 @@ def test_predict_rank_matches_two_stage_oracle(n, m1, K, m2):
                                        interpret=True)
         want = rank_given_lambda(u, a, b, pred.predict(X), gamma, m2=m2)
         _assert_fields_equal(got, want, msg=f"[{name}]")
-        if name == "knn":
-            # per-tile distance accumulation: λ̂ to the last ulp
-            np.testing.assert_allclose(
-                np.asarray(got.lam), np.asarray(want.lam),
-                rtol=1e-5, atol=1e-6, err_msg="fused KNN weighting drifted")
-        else:
-            # affine prologue / in-executable MLP: λ̂ bitwise
-            np.testing.assert_array_equal(
-                np.asarray(got.lam), np.asarray(want.lam),
-                err_msg=f"λ̂ parity broke for {name}")
+        # in-kernel prologue / KNN weighting vs eager predict(): λ̂ to a
+        # few ulp (the dot's reduction order is the compiler's choice)
+        np.testing.assert_allclose(
+            np.asarray(got.lam), np.asarray(want.lam),
+            rtol=PARITY_RTOL, atol=PARITY_ATOL,
+            err_msg=f"λ̂ parity broke for {name}")
 
 
 def test_mean_family_preserves_unclamped_negative_lambda():
@@ -137,11 +143,8 @@ def test_predict_rank_bucket_padded_batch():
         lam = jnp.pad(pred.predict(X), ((0, 0), (0, bucket.K - K_pred)))
         want = rank_given_lambda(u, a, b, lam, gamma, m2=bucket.m2)
         n_real = len(reqs)
-        for field in FIELDS:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(got, field))[:n_real],
-                np.asarray(getattr(want, field))[:n_real],
-                err_msg=f"padded covariate batch broke on {field}")
+        _assert_fields_equal(got, want, rows=slice(None, n_real),
+                             msg="(padded covariate batch)")
         # phantom rows: zero gamma -> zero utility, trivially compliant
         np.testing.assert_array_equal(np.asarray(got.utility[n_real:]), 0.0)
         assert bool(np.all(np.asarray(got.compliant[n_real:])))
