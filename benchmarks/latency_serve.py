@@ -14,8 +14,8 @@ Three measurement modes (docs/benchmarks.md walks through them):
     pipelined (pipeline_depth=1 double buffering) — reported side by
     side: per-request p50/p95/p99 (enqueue -> result) from a
     deadline-driven run, saturated wall-clock throughput and the
-    pipelined/sync speedup from paired interleaved trials, overlap
-    ratio, compliance, bucket fill rate, and recompiles after warmup
+    pipelined/sync speedup from paired interleaved trials, compliance,
+    bucket fill rate, and recompiles after warmup
     (must stay 0). Both modes must produce identical perms per rid
     (verified here, not just in tests). This is the fleet-relevant
     number: the deployed system sees a stream, not a fixed batch.
@@ -278,7 +278,6 @@ def _run_engine_inproc(*, n_requests, max_batch, max_wait_ms, scenarios,
             "throughput_rps": round(n_requests / wall_med, 1),
             "speedup_vs_sync": round(statistics.median(ratios), 2),
             "speedup_spread": [round(ratios[0], 2), round(ratios[-1], 2)],
-            "overlap_ratio": s["pipeline"]["overlap_ratio"],
             "queue_depth_max": s["pipeline"]["queue_depth_max"],
             "perms_match_baseline": bool(identical),
             "compliance": s["compliance"],
@@ -292,8 +291,7 @@ def _run_engine_inproc(*, n_requests, max_batch, max_wait_ms, scenarios,
                   f"{r['throughput_rps']:7.1f} req/s "
                   f"(median {r['speedup_vs_sync']:.2f}x, spread "
                   f"{r['speedup_spread'][0]:.2f}-{r['speedup_spread'][1]:.2f})"
-                  f"  overlap {r['overlap_ratio']:.2f}  "
-                  f"perms_match {r['perms_match_baseline']}  "
+                  f"  perms_match {r['perms_match_baseline']}  "
                   f"recompiles {r['compiles_post_warmup']}", flush=True)
     for eng in engines.values():
         eng.close()
@@ -1542,7 +1540,6 @@ def records_engine(rows):
                  "p99_ms": r["p99_ms"], "fill": r["fill_rate"],
                  "throughput_rps": r["throughput_rps"],
                  "speedup_vs_sync": r["speedup_vs_sync"],
-                 "overlap": r["overlap_ratio"],
                  "perms_match": r["perms_match_baseline"],
                  "recompiles_post_warmup": r["compiles_post_warmup"],
                  "within_50ms": r["within_50ms"]})

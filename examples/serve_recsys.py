@@ -131,8 +131,8 @@ def main():
           f"p99 {lat['p99']:7.2f} ms  (per request, enqueue -> result)")
     print(f"  compliance {s['compliance']:.2f}")
     p = s["pipeline"]
-    print(f"  pipeline depth {args.pipeline_depth}: overlap "
-          f"{p['overlap_ratio']:.0%}, max in-flight {p['queue_depth_max']}, "
+    print(f"  pipeline depth {args.pipeline_depth}: max in-flight "
+          f"{p['queue_depth_max']}, "
           f"exec p50 {p['exec_ms_per_batch']['p50']:.2f} ms/batch")
     print(f"  recompiles after warmup: {s['compiles_post_warmup']}")
     d = s["deadline"]

@@ -30,24 +30,33 @@ from heterogeneous upstream recommenders; the engine:
      collect path behind submit/poll/drain — never on the worker.
 
 Steady state therefore never recompiles (the jit cache is the bucket
-lattice, populated by `warmup` — the only place `block_until_ready`
-survives), never pays per-request dispatch (amortized over the
-micro-batch), and never serializes host assembly against device
-execution (the sole job of the old blocking `rank()` call, retired in
-favor of futures). `pipeline_depth` bounds the in-flight window —
-depth 1 (the default) is classic double buffering: one batch
-materializing while the next is assembled and dispatched; depth 0
-recovers the synchronous single-threaded engine (same results, no
-overlap), which is what the sync column of
+lattice, populated by `warmup`), never pays per-request dispatch
+(amortized over the micro-batch), and never serializes host assembly
+against device execution (the sole job of the old blocking `rank()`
+call, retired in favor of futures). `pipeline_depth` bounds the
+in-flight window — depth 1 (the default) is classic double buffering:
+one batch materializing while the next is assembled and dispatched;
+depth 0 recovers the synchronous single-threaded engine (same results,
+no overlap), which is what the sync column of
 benchmarks/latency_serve.py measures and what the equivalence tests
 in tests/test_serving_pipeline.py compare against.
 
-See docs/serving.md for timelines and backpressure semantics, and
-docs/api.md for the public API.
+Every layer boundary is a `jax.profiler.TraceAnnotation` span
+(`engine.*`, `pipeline.*`; docs/serving.md lists them), so a profiler
+trace puts the engine's host work on the device's clock. Each span of
+one micro-batch carries the flush's sequence number as its `batch`
+stat (PendingBatch.seq), each request's spans its `rid`. With no trace
+running a span records nothing and the completion worker skips its
+explicit device wait, so the profiler itself is the switch.
+
+See docs/serving.md for timelines, backpressure semantics and the
+spans, and docs/api.md for the public API.
 """
 
 from __future__ import annotations
 
+import itertools
+import re
 import threading
 import time
 from dataclasses import dataclass
@@ -58,6 +67,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation as span
 
 from repro.core.predictors import predictor_state, with_state
 from repro.core.ranking import RankingOutput, rank_given_lambda
@@ -85,6 +95,21 @@ LAM_TAG = "_lam"   # requests that carry shadow prices directly
 
 # Default per-request latency budget: the paper's 50 ms claim.
 DEFAULT_BUDGET_S = 0.050
+
+
+def executable_name(bucket: Bucket) -> str:
+    """The bucket executable's name in compiled text and device traces,
+    e.g. ``serve_knn_m1_65536_m2_64_K_8_B_32``."""
+    tag = re.sub(r"\W", "_", bucket.tag).strip("_")
+    return (f"serve_{tag}_m1_{bucket.m1}_m2_{bucket.m2}_K_{bucket.K}"
+            f"_B_{bucket.batch}")
+
+
+def _jit(bucket: Bucket, fn: Callable, **kw) -> Callable:
+    """``jax.jit`` of a bucket's body under the bucket's own name, so
+    each executable is told apart in a trace (not ``jit_fn(<hash>)``)."""
+    fn.__name__ = fn.__qualname__ = executable_name(bucket)
+    return jax.jit(fn, **kw)
 
 
 @dataclass
@@ -298,9 +323,11 @@ class ServingEngine:
         self._kernel_launches: dict[Bucket, int] = {}
         self._queues: dict[Bucket, list] = {}
         self._rings: dict[Bucket, StagingRing] = {}
+        self._d2h_nbytes: dict[Bucket, int] = {}   # pipeline.d2h's bytes
         self._warmed: set[Bucket] = set()
         self._in_warmup = False           # re-warm compiles aren't violations
         self._retired_sync: list = []     # sync-mode batches awaiting collect
+        self._batch_seq = itertools.count()   # PendingBatch.seq, per flush
         self._pipeline = (ExecutionPipeline(depth=self.pipeline_depth)
                           if self.pipeline_depth > 0 else None)
 
@@ -731,8 +758,9 @@ class ServingEngine:
             def fn(b, gamma, u, a, lam):
                 return rank(u, a, b, lam, gamma)
 
-            return jax.jit(fn, donate_argnums=(2, 3) if self.donate else (),
-                           **placed)
+            return _jit(bucket, fn,
+                        donate_argnums=(2, 3) if self.donate else (),
+                        **placed)
 
         # Predictor-tagged buckets take the predictor's ARRAY state as
         # argument 0 instead of closing over it: closed-over arrays are
@@ -756,7 +784,7 @@ class ServingEngine:
                 lam = jnp.pad(lam, ((0, 0), (0, pad_k)))
                 return rank(u, a, b, lam, gamma)
 
-            return jax.jit(fn, donate_argnums=donate, **placed)
+            return _jit(bucket, fn, donate_argnums=donate, **placed)
 
         # The single-sweep dispatcher (kernels.ops.predict_rank_audited
         # behind its stateful seam): predict + rank + audit lower to
@@ -792,7 +820,7 @@ class ServingEngine:
                                                  use_kernel=use_kernel,
                                                  **tiles)
 
-        return jax.jit(fn, donate_argnums=donate)
+        return _jit(bucket, fn, donate_argnums=donate)
 
     def _executor_for(self, bucket: Bucket) -> Callable:
         fn = self._exec.get(bucket)
@@ -919,50 +947,52 @@ class ServingEngine:
     def _enqueue(self, req: RankRequest, now: float | None) -> RankFuture:
         now = self.clock() if now is None else now
         bucket = self.bucket_of(req)
-        self.metrics.on_submit(bucket, known=bucket in self._warmed)
-        # shape telemetry: the request's REAL geometry (pre-padding,
-        # pre-K-widening) — what the lattice optimizer learns corners
-        # from. A dict update per request; no device reads.
-        self.shape_histogram.observe(
-            tag=bucket.tag, m1=req.u.shape[0], m2=req.m2,
-            K=req.a.shape[0],
-            d_cov=None if req.X is None else req.X.shape[-1],
-            surface=req.surface)
-        if self._lattice_lane is not None:
-            self._lattice_lane.observe_arrival(now)
-        fut = RankFuture(req.rid, bucket.name)
-        deadline = self._deadline_of(req, now)
-        rung = 0
-        if self.admission is not None:
-            rungs = self._rung_buckets(req, bucket)
-            inflight = (self._pipeline.inflight()
-                        if self._pipeline is not None else 0)
-            preds = [(r, self.admission.predict_ms(
-                          bk.name,
-                          queue_len=len(self._queues.get(bk, ())),
-                          batch_cap=bk.batch, inflight=inflight,
-                          max_wait_ms=self.max_wait_ms))
-                     for r, bk in rungs]
-            decision = self.admission.decide(
-                budget_ms=(deadline - now) * 1e3, rung_predictions=preds)
-            if not decision.admitted:
-                self.metrics.on_shed(bucket, surface=req.surface)
-                shed = Shed(rid=req.rid, bucket=bucket.name,
-                            predicted_ms=decision.predicted_ms,
-                            budget_ms=decision.budget_ms)
-                fut._resolve(shed)
-                self._uncollected_sheds.append(shed)
-                return fut
-            if decision.rung > 0:
-                rung = decision.rung
-                bucket = dict(rungs)[rung]
-                self.metrics.on_degrade(rung, surface=req.surface)
-        q = self._queues.setdefault(bucket, [])
-        q.append(_QueueEntry(req=req, t_enq=now, fut=fut,
-                             deadline=deadline, rung=rung))
-        if len(q) >= bucket.batch:
-            self._flush_bucket(bucket, trigger="capacity")
-        return fut
+        name = bucket.name
+        with span("engine.enqueue", rid=req.rid, bucket=name):
+            self.metrics.on_submit(bucket, known=bucket in self._warmed)
+            # shape telemetry: the request's REAL geometry (pre-padding,
+            # pre-K-widening) — what the lattice optimizer learns corners
+            # from. A dict update per request; no device reads.
+            self.shape_histogram.observe(
+                tag=bucket.tag, m1=req.u.shape[0], m2=req.m2,
+                K=req.a.shape[0],
+                d_cov=None if req.X is None else req.X.shape[-1],
+                surface=req.surface)
+            if self._lattice_lane is not None:
+                self._lattice_lane.observe_arrival(now)
+            fut = RankFuture(req.rid, name)
+            deadline = self._deadline_of(req, now)
+            rung = 0
+            if self.admission is not None:
+                rungs = self._rung_buckets(req, bucket)
+                inflight = (self._pipeline.inflight()
+                            if self._pipeline is not None else 0)
+                preds = [(r, self.admission.predict_ms(
+                              bk.name,
+                              queue_len=len(self._queues.get(bk, ())),
+                              batch_cap=bk.batch, inflight=inflight,
+                              max_wait_ms=self.max_wait_ms))
+                         for r, bk in rungs]
+                decision = self.admission.decide(
+                    budget_ms=(deadline - now) * 1e3, rung_predictions=preds)
+                if not decision.admitted:
+                    self.metrics.on_shed(bucket, surface=req.surface)
+                    shed = Shed(rid=req.rid, bucket=bucket.name,
+                                predicted_ms=decision.predicted_ms,
+                                budget_ms=decision.budget_ms)
+                    fut._resolve(shed)
+                    self._uncollected_sheds.append(shed)
+                    return fut
+                if decision.rung > 0:
+                    rung = decision.rung
+                    bucket = dict(rungs)[rung]
+                    self.metrics.on_degrade(rung, surface=req.surface)
+            q = self._queues.setdefault(bucket, [])
+            q.append(_QueueEntry(req=req, t_enq=now, fut=fut,
+                                 deadline=deadline, rung=rung))
+            if len(q) >= bucket.batch:
+                self._flush_bucket(bucket, trigger="capacity")
+            return fut
 
     def poll(self, now: float | None = None):
         """Deadline check: flush every queue whose oldest request has
@@ -1054,64 +1084,76 @@ class ServingEngine:
     def _flush_bucket(self, bucket: Bucket, *, trigger: str) -> None:
         entries = self._queues[bucket]
         self._queues[bucket] = []
-        reqs = [e.req for e in entries]
-        ring = self._ring_for(bucket)
-        fn = self._executor_for(bucket)
-        t0 = self.clock()
-        staged = fill_staging(ring.acquire(), reqs, bucket)
-        # epoch fence: the (state, epoch) pair is read atomically, so
-        # this whole batch dispatches against exactly one predictor
-        # generation — a concurrent swap lands before or after, never
-        # inside. The generation stays pinned until the batch
-        # materializes (_release_gen), which is what delays retirement
-        # of superseded device buffers past their last in-flight use.
-        state, epoch = ((None, 0) if bucket.tag == LAM_TAG
-                        else self._current_gen(bucket.tag))
-        # lattice fence, same discipline: the epoch is read under the
-        # swap lock, so a concurrent swap_lattice lands before or after
-        # this batch, never inside it.
-        with self._swap_lock:
-            lattice_epoch = self._lattice_epoch
-        t_launch = self.clock()
-        try:
-            out = self._call(fn, bucket, staged, state)  # async: no block
-        except BaseException as e:                # noqa: BLE001
-            # dispatch itself blew up (bad executable, device OOM, an
-            # injected fault): fail this batch's futures so every one
-            # still resolves exactly once, and recycle the staging set
-            # — the ring is finite, and a leaked buffer would
-            # eventually deadlock acquire() under continued load.
-            for entry in entries:
-                entry.fut._fail(e)
-            ring.release(staged)
-            if bucket.tag != LAM_TAG:      # nothing dispatched: unpin
-                self._release_gen(bucket.tag, epoch)
-            raise
-        t1 = self.clock()
-        # the single-dispatch contract: this _call was the batch's ONE
-        # executable invocation — predictor buckets included (λ̂ is
-        # predicted inside the executable, never as a separate program)
-        # — and it contained the route's static kernel-launch count
-        # (ONE for every fused-executor kernel bucket, KNN included
-        # since the single-grid predict+rank+audit kernel).
-        self.metrics.on_executable_call(self._kernel_launches[bucket])
-        pending = PendingBatch(
-            bucket=bucket, entries=entries,
-            futures=[e.fut for e in entries], out=out, staged=staged,
-            ring=ring, t_launch=t_launch, trigger=trigger,
-            materialize=self._materialize_batch, build=self._build_result,
-            assembly_ms=(t_launch - t0) * 1e3,
-            dispatch_ms=(t1 - t_launch) * 1e3, epoch=epoch,
-            lattice_epoch=lattice_epoch)
-        if self._pipeline is not None:
-            self._pipeline.submit(pending)      # may block: backpressure
-        else:
-            pending.finish()
-            self._retired_sync.append(pending)
-        self.metrics.on_dispatch(
-            bucket, len(reqs), trigger, fill_stats(reqs, bucket),
-            assembly_ms=pending.assembly_ms, dispatch_ms=pending.dispatch_ms,
-            depth=pending.depth_at_dispatch, t_now=t_launch)
+        seq = next(self._batch_seq)
+        with span("engine.flush", batch=seq, bucket=bucket.name,
+                  trigger=trigger, rows=len(entries)):
+            reqs = [e.req for e in entries]
+            ring = self._ring_for(bucket)
+            fn = self._executor_for(bucket)
+            t0 = self.clock()
+            staged = ring.acquire()
+            with span("engine.fill_staging", batch=seq):
+                fill_staging(staged, reqs, bucket)
+            # epoch fence: the (state, epoch) pair is read atomically, so
+            # this whole batch dispatches against exactly one predictor
+            # generation — a concurrent swap lands before or after, never
+            # inside. The generation stays pinned until the batch
+            # materializes (_release_gen), which is what delays retirement
+            # of superseded device buffers past their last in-flight use.
+            state, epoch = ((None, 0) if bucket.tag == LAM_TAG
+                            else self._current_gen(bucket.tag))
+            # lattice fence, same discipline: the epoch is read under the
+            # swap lock, so a concurrent swap_lattice lands before or
+            # after this batch, never inside it.
+            with self._swap_lock:
+                lattice_epoch = self._lattice_epoch
+            t_launch = self.clock()
+            try:
+                # the jit call queues the copies of the staged host
+                # arrays to the device and launches: async, no block.
+                # The runtime's own transfer events in a trace say when
+                # each copy is done, often after this span has ended.
+                with span("engine.dispatch", batch=seq, bytes=ring.nbytes):
+                    out = self._call(fn, bucket, staged, state)
+            except BaseException as e:                # noqa: BLE001
+                # dispatch itself blew up (bad executable, device OOM, an
+                # injected fault): fail this batch's futures so every one
+                # still resolves exactly once, and recycle the staging set
+                # — the ring is finite, and a leaked buffer would
+                # eventually deadlock acquire() under continued load.
+                for entry in entries:
+                    entry.fut._fail(e)
+                ring.release(staged)
+                if bucket.tag != LAM_TAG:      # nothing dispatched: unpin
+                    self._release_gen(bucket.tag, epoch)
+                raise
+            t1 = self.clock()
+            # the single-dispatch contract: this call was the batch's ONE
+            # executable invocation — predictor buckets included (λ̂ is
+            # predicted inside the executable, never as a separate
+            # program) — and it contained the route's static kernel-launch
+            # count (ONE for every fused-executor kernel bucket, KNN
+            # included since the single-grid predict+rank+audit kernel).
+            self.metrics.on_executable_call(self._kernel_launches[bucket])
+            pending = PendingBatch(
+                bucket=bucket, entries=entries,
+                futures=[e.fut for e in entries], out=out, staged=staged,
+                ring=ring, t_launch=t_launch, trigger=trigger,
+                materialize=self._materialize_batch,
+                build=self._build_result,
+                assembly_ms=(t_launch - t0) * 1e3,
+                dispatch_ms=(t1 - t_launch) * 1e3, epoch=epoch,
+                lattice_epoch=lattice_epoch, seq=seq)
+            if self._pipeline is not None:
+                self._pipeline.submit(pending)   # may block: backpressure
+            else:
+                pending.finish()
+                self._retired_sync.append(pending)
+            self.metrics.on_dispatch(
+                bucket, len(reqs), trigger, fill_stats(reqs, bucket),
+                assembly_ms=pending.assembly_ms,
+                dispatch_ms=pending.dispatch_ms,
+                depth=pending.depth_at_dispatch, t_now=t_launch)
 
     # -- completion side ----------------------------------------------------
 
@@ -1125,13 +1167,27 @@ class ServingEngine:
         then pure numpy (slicing jax arrays row-by-row would dispatch —
         and on first touch compile — one tiny program per slice)."""
         out = pending.out
+        # Under a trace, wait for the device (behind earlier batches
+        # too) apart from the copy home that follows. The extra wait
+        # hands the interpreter lock over once more a batch, which cost
+        # about 3 % of the feed cell's p50 on a TPU v5e, so it runs only
+        # while a trace records; the copies below wait anyway.
+        if span.is_enabled():
+            with span("pipeline.device_wait", batch=pending.seq):
+                jax.block_until_ready(out)
         # lam comes home with the rest: the refresh lane's telemetry
         # (λ̂ actually served) reads it row-by-row in _build_result, and
         # slicing a device array there would dispatch per row.
-        pending.out = RankingOutput(
-            perm=np.asarray(out.perm), utility=np.asarray(out.utility),
-            exposure=np.asarray(out.exposure),
-            compliant=np.asarray(out.compliant), lam=np.asarray(out.lam))
+        nbytes = self._d2h_nbytes.get(pending.bucket)
+        if nbytes is None:          # fixed by the bucket's shapes
+            nbytes = self._d2h_nbytes[pending.bucket] = sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(out))
+        with span("pipeline.d2h", batch=pending.seq, bytes=nbytes):
+            pending.out = RankingOutput(
+                perm=np.asarray(out.perm), utility=np.asarray(out.utility),
+                exposure=np.asarray(out.exposure),
+                compliant=np.asarray(out.compliant),
+                lam=np.asarray(out.lam))
         pending.t_done = self.clock()
         exec_ms = (pending.t_done - pending.t_launch) * 1e3
         self.metrics.on_retire(exec_ms, pending.t_done)
@@ -1148,6 +1204,11 @@ class ServingEngine:
         per row (memoized by the row's RankFuture), on whichever
         consumer thread first asks — the engine's collect path or a
         direct future.result() call."""
+        with span("engine.build_result", rid=pending.entries[i].req.rid,
+                  batch=pending.seq):
+            return self._unpad_row(pending, i)
+
+    def _unpad_row(self, pending: PendingBatch, i: int) -> RankResult:
         entry = pending.entries[i]
         req, t_enq = entry.req, entry.t_enq
         perm, utility, exposure, compliant = unpad_result(pending.out, i, req)

@@ -69,9 +69,6 @@ class EngineMetrics:
     dispatch_ms: list = field(default_factory=list)   # jit call -> futures
     exec_ms: list = field(default_factory=list)       # launch -> outputs home
     queue_depth: list = field(default_factory=list)   # in-flight at dispatch
-    # serving window (for the overlap ratio): first dispatch, last retire
-    t_first_dispatch: float | None = None
-    t_last_retire: float | None = None
     # quality / latency
     compliant_sum: float = 0.0
     latencies_ms: list = field(default_factory=list)
@@ -98,7 +95,6 @@ class EngineMetrics:
     swaps: int = 0
     refresh_failures: int = 0
     states_retired: int = 0
-    swaps_by_tag: dict = field(default_factory=lambda: defaultdict(int))
     # adaptive-lattice lane accounting: lattice_swaps = lattice
     # generations flipped live (engine.swap_lattice successes, == the
     # live lattice epoch); lattice_rollbacks = re-warm attempts that
@@ -156,7 +152,10 @@ class EngineMetrics:
     def on_dispatch(self, bucket, n_real: int, trigger: str, fill: dict,
                     *, assembly_ms: float, dispatch_ms: float,
                     depth: int, t_now: float) -> None:
-        """Submission side: a micro-batch was assembled and launched."""
+        """Submission side: a micro-batch was assembled and launched at
+        ``t_now`` (engine clock). This class keeps no clock; a subclass
+        that places batches in time reads ``t_now`` (the on-chip
+        benchmark's RecordingMetrics)."""
         self.batches += 1
         self.assembly_ms.append(assembly_ms)
         self.dispatch_ms.append(dispatch_ms)
@@ -171,13 +170,11 @@ class EngineMetrics:
         self.padded_cells += fill["padded_cells"]
         self.real_flops += fill.get("real_flops", 0)
         self.padded_flops += fill.get("padded_flops", 0)
-        if self.t_first_dispatch is None:
-            self.t_first_dispatch = t_now
 
     def on_retire(self, exec_ms: float, t_now: float) -> None:
-        """Completion side: a micro-batch's outputs reached the host."""
+        """Completion side: a micro-batch's outputs reached the host at
+        ``t_now`` (engine clock; for subclasses, as in ``on_dispatch``)."""
         self.exec_ms.append(exec_ms)
-        self.t_last_retire = t_now
 
     def on_result(self, latency_ms: float, wait_ms: float,
                   compliant: bool, *, deadline_hit: bool | None = None,
@@ -220,7 +217,6 @@ class EngineMetrics:
         flipped live (engine.swap_predictor succeeded)."""
         with self._result_lock:
             self.swaps += 1
-            self.swaps_by_tag[tag] += 1
 
     def on_refresh_failure(self, tag: str) -> None:
         """Refresh lane: a refresh attempt failed (state the engine
@@ -266,25 +262,6 @@ class EngineMetrics:
         arr = np.asarray(xs)
         return {f"p{q}": round(float(np.percentile(arr, q)), 3) for q in qs}
 
-    def overlap_ratio(self) -> float:
-        """How much pipelining compressed the serving window.
-
-        serial = what the stages would cost laid end to end
-        (Σ assembly + Σ dispatch + Σ execute/transfer); wall = first
-        dispatch → last retire. 0 means fully serialized (the sync
-        engine), values toward 1 mean host assembly ran almost entirely
-        under device execution. Only meaningful for back-to-back
-        streams — arrival gaps inflate the wall and deflate the ratio.
-        """
-        if self.t_first_dispatch is None or self.t_last_retire is None:
-            return 0.0
-        serial = (sum(self.assembly_ms) + sum(self.dispatch_ms)
-                  + sum(self.exec_ms))
-        wall = (self.t_last_retire - self.t_first_dispatch) * 1e3
-        if serial <= 0.0 or wall <= 0.0:
-            return 0.0
-        return max(0.0, min(1.0, 1.0 - wall / serial))
-
     def summary(self) -> dict:
         lat = self._pct(self.latencies_ms)
         return {
@@ -318,7 +295,6 @@ class EngineMetrics:
                 "queue_depth_max": max(self.queue_depth, default=0),
                 "queue_depth_mean": round(float(np.mean(self.queue_depth)), 3)
                                     if self.queue_depth else 0.0,
-                "overlap_ratio": round(self.overlap_ratio(), 3),
             },
             "compliance": round(self.compliant_sum / self.results, 3)
                           if self.results else float("nan"),
@@ -357,7 +333,6 @@ class EngineMetrics:
         whose buffers were released."""
         return {
             "swaps": self.swaps,
-            "swaps_by_tag": dict(self.swaps_by_tag),
             "refresh_failures": self.refresh_failures,
             "states_retired": self.states_retired,
         }

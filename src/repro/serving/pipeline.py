@@ -49,6 +49,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation as span
+
 from repro.serving.buckets import Bucket, alloc_staging
 
 __all__ = ["RankFuture", "StagingRing", "PendingBatch", "ExecutionPipeline"]
@@ -182,6 +184,8 @@ class StagingRing:
             owned.append(id(staged))
             self._free.put(staged)
         self._owned = frozenset(owned)
+        # bytes of one buffer set: what a batch copies to the device
+        self.nbytes = sum(x.nbytes for x in staged.values())
 
     @property
     def allocated(self) -> int:
@@ -243,6 +247,9 @@ class PendingBatch:
     # swap lands between batches, so every row of a batch was bucketed
     # and served under one lattice. 0 = the boot power-of-two lattice.
     lattice_epoch: int = 0
+    # the flush's sequence number: the `batch` stat of every trace span
+    # of this batch
+    seq: int = -1
 
     def finish(self) -> None:
         """Materialize outputs and mark every future done. Called by
@@ -323,7 +330,8 @@ class ExecutionPipeline:
             raise RuntimeError("pipeline is closed")
         self._ensure_worker()
         pending.depth_at_dispatch = self._inflight.qsize()
-        self._inflight.put(pending)
+        with span("pipeline.backpressure", batch=pending.seq):
+            self._inflight.put(pending)
         self._raise_pending_error()
 
     def inflight(self) -> int:
