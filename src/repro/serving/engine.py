@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation as span
 
 from repro.core.predictors import predictor_state, with_state
-from repro.core.ranking import RankingOutput, rank_given_lambda
+from repro.core.ranking import rank_given_lambda
 from repro.serving.admission import SHED_RUNG, AdmissionController
 from repro.serving.buckets import (
     AUTOTUNE_KEYS,
@@ -84,6 +84,7 @@ from repro.serving.buckets import (
 )
 from repro.serving.lattice import Lattice, ShapeHistogram
 from repro.serving.metrics import EngineMetrics
+from repro.serving.outputs import OutputSlab
 from repro.serving.pipeline import (
     ExecutionPipeline,
     PendingBatch,
@@ -323,7 +324,7 @@ class ServingEngine:
         self._kernel_launches: dict[Bucket, int] = {}
         self._queues: dict[Bucket, list] = {}
         self._rings: dict[Bucket, StagingRing] = {}
-        self._d2h_nbytes: dict[Bucket, int] = {}   # pipeline.d2h's bytes
+        self._slabs: dict[Bucket, OutputSlab] = {}   # output layouts
         self._warmed: set[Bucket] = set()
         self._in_warmup = False           # re-warm compiles aren't violations
         self._retired_sync: list = []     # sync-mode batches awaiting collect
@@ -649,10 +650,10 @@ class ServingEngine:
                 continue
             fn = self._build_executor(bucket)
             staged = assemble_batch([], bucket, d_cov=self._dcov(bucket))
-            jax.block_until_ready(self._call(fn, bucket, staged).perm)
+            jax.block_until_ready(self._call(fn, bucket, staged))
             if self.admission is not None:
                 t0b = self.clock()
-                jax.block_until_ready(self._call(fn, bucket, staged).perm)
+                jax.block_until_ready(self._call(fn, bucket, staged))
                 self.admission.observe_service(
                     bucket.name, (self.clock() - t0b) * 1e3)
             with self._swap_lock:
@@ -734,7 +735,10 @@ class ServingEngine:
 
     def _build_executor(self, bucket: Bucket) -> Callable:
         """One fresh jit wrapper per bucket: its compile cache holds
-        exactly one entry, so `jit_cache_sizes` exposes recompiles."""
+        exactly one entry, so `jit_cache_sizes` exposes recompiles.
+        Every body returns its outputs packed into the bucket's one
+        int32 slab (serving.outputs), which `_materialize_batch` copies
+        home in a single transfer."""
         from repro.kernels.ops import kernel_launch_count
 
         predictor = (None if bucket.tag == LAM_TAG
@@ -742,6 +746,17 @@ class ServingEngine:
         self._kernel_launches[bucket] = (
             kernel_launch_count(predictor, bucket.m2)
             if self.executor == "fused" else 0)
+        body, jit_kw = self._bucket_body(bucket)
+        slab = self._slabs[bucket] = OutputSlab(m2=bucket.m2, K=bucket.K)
+
+        def fn(*args):
+            return slab.pack(body(*args))
+
+        return _jit(bucket, fn, **jit_kw)
+
+    def _bucket_body(self, bucket: Bucket) -> tuple[Callable, dict]:
+        """The bucket's unjitted body, which returns a RankingOutput,
+        and the keyword arguments of its jit (donation, placement)."""
         rank = self._rank_fn(bucket)
         # dist: host staging transfers straight into the mesh layout
         # rank_distributed computes in, one shard per device
@@ -758,9 +773,8 @@ class ServingEngine:
             def fn(b, gamma, u, a, lam):
                 return rank(u, a, b, lam, gamma)
 
-            return _jit(bucket, fn,
-                        donate_argnums=(2, 3) if self.donate else (),
-                        **placed)
+            return fn, dict(donate_argnums=(2, 3) if self.donate else (),
+                            **placed)
 
         # Predictor-tagged buckets take the predictor's ARRAY state as
         # argument 0 instead of closing over it: closed-over arrays are
@@ -784,7 +798,7 @@ class ServingEngine:
                 lam = jnp.pad(lam, ((0, 0), (0, pad_k)))
                 return rank(u, a, b, lam, gamma)
 
-            return _jit(bucket, fn, donate_argnums=donate, **placed)
+            return fn, dict(donate_argnums=donate, **placed)
 
         # The single-sweep dispatcher (kernels.ops.predict_rank_audited
         # behind its stateful seam): predict + rank + audit lower to
@@ -820,7 +834,7 @@ class ServingEngine:
                                                  use_kernel=use_kernel,
                                                  **tiles)
 
-        return _jit(bucket, fn, donate_argnums=donate)
+        return fn, dict(donate_argnums=donate)
 
     def _executor_for(self, bucket: Bucket) -> Callable:
         fn = self._exec.get(bucket)
@@ -853,11 +867,10 @@ class ServingEngine:
             for bucket in sorted(buckets):
                 fn = self._executor_for(bucket)
                 staged = assemble_batch([], bucket, d_cov=self._dcov(bucket))
-                jax.block_until_ready(self._call(fn, bucket, staged).perm)
+                jax.block_until_ready(self._call(fn, bucket, staged))
                 if self.admission is not None:
                     t0 = self.clock()
-                    jax.block_until_ready(
-                        self._call(fn, bucket, staged).perm)
+                    jax.block_until_ready(self._call(fn, bucket, staged))
                     self.admission.observe_service(
                         bucket.name, (self.clock() - t0) * 1e3)
                 self._warmed.add(bucket)
@@ -884,7 +897,7 @@ class ServingEngine:
                 staged["a"], staged["X"])
 
     def _call(self, fn, bucket: Bucket, staged: dict,
-              state: dict | None = None) -> RankingOutput:
+              state: dict | None = None) -> jax.Array:
         return fn(*self._args(bucket, staged, state))
 
     def compiled_executables(self) -> dict[str, jax.stages.Compiled]:
@@ -1163,31 +1176,26 @@ class ServingEngine:
         ONLY blocking step on the completion side — the GIL is released
         while waiting, so the submission thread keeps assembling.
 
-        One bulk device->host copy per output; per-request unpadding is
-        then pure numpy (slicing jax arrays row-by-row would dispatch —
-        and on first touch compile — one tiny program per slice)."""
-        out = pending.out
+        The executable returned its outputs as one int32 slab, so they
+        come home in ONE device->host copy, and the RankingOutput is
+        rebuilt from numpy views of it; per-request unpadding is then
+        pure numpy (slicing jax arrays row-by-row would dispatch — and
+        on first touch compile — one tiny program per slice)."""
+        slab = pending.out
         # Under a trace, wait for the device (behind earlier batches
         # too) apart from the copy home that follows. The extra wait
         # hands the interpreter lock over once more a batch, which cost
         # about 3 % of the feed cell's p50 on a TPU v5e, so it runs only
-        # while a trace records; the copies below wait anyway.
+        # while a trace records; the copy below waits anyway.
         if span.is_enabled():
             with span("pipeline.device_wait", batch=pending.seq):
-                jax.block_until_ready(out)
+                jax.block_until_ready(slab)
         # lam comes home with the rest: the refresh lane's telemetry
         # (λ̂ actually served) reads it row-by-row in _build_result, and
         # slicing a device array there would dispatch per row.
-        nbytes = self._d2h_nbytes.get(pending.bucket)
-        if nbytes is None:          # fixed by the bucket's shapes
-            nbytes = self._d2h_nbytes[pending.bucket] = sum(
-                x.nbytes for x in jax.tree_util.tree_leaves(out))
-        with span("pipeline.d2h", batch=pending.seq, bytes=nbytes):
-            pending.out = RankingOutput(
-                perm=np.asarray(out.perm), utility=np.asarray(out.utility),
-                exposure=np.asarray(out.exposure),
-                compliant=np.asarray(out.compliant),
-                lam=np.asarray(out.lam))
+        with span("pipeline.d2h", batch=pending.seq, bytes=slab.nbytes,
+                  copies=1):
+            pending.out = self._slabs[pending.bucket].unpack(np.asarray(slab))
         pending.t_done = self.clock()
         exec_ms = (pending.t_done - pending.t_launch) * 1e3
         self.metrics.on_retire(exec_ms, pending.t_done)

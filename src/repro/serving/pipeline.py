@@ -227,7 +227,7 @@ class PendingBatch:
     entries: list                     # [engine._QueueEntry] (req, t_enq,
                                       # deadline, rung)
     futures: list                     # [RankFuture], aligned with entries
-    out: Any                          # RankingOutput: device, then host arrays
+    out: Any                          # device slab, then a host RankingOutput
     staged: dict | None               # staging buffers to recycle
     ring: StagingRing | None
     t_launch: float

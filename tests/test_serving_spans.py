@@ -30,7 +30,7 @@ SPANS = {
     "engine.dispatch": ("batch", "bytes"),
     "pipeline.backpressure": ("batch",),
     "pipeline.device_wait": ("batch",),
-    "pipeline.d2h": ("batch", "bytes"),
+    "pipeline.d2h": ("batch", "bytes", "copies"),
     "engine.build_result": ("rid", "batch"),
 }
 BATCH_SPANS = ("engine.fill_staging", "engine.dispatch",
@@ -223,6 +223,50 @@ def test_device_wait_blocks_only_under_a_trace(tmp_path, monkeypatch):
     assert waits and len(waits) == len({st["batch"] for name, *_, st in
                               _events(tmp_path) if name == "engine.flush"})
     eng.close()
+
+
+class _CopySpy:
+    """numpy, counting the conversions of device arrays to host arrays:
+    each is one copy home."""
+
+    def __init__(self):
+        self.copies = 0
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, x, *args, **kw):
+        self.copies += isinstance(x, jax.Array)
+        return np.asarray(x, *args, **kw)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_a_batch_comes_home_in_one_copy(tmp_path, monkeypatch, depth):
+    """Sync and async: one device-to-host copy a materialized batch,
+    and `pipeline.d2h` says so: `copies` 1, `bytes` the whole slab."""
+    import repro.serving.engine as engine_mod
+
+    eng, reqs = _engine(pipeline_depth=depth)
+    spy = _CopySpy()
+    monkeypatch.setattr(engine_mod, "np", spy)
+    _start_trace(tmp_path)
+    try:
+        for r in reqs:
+            eng.submit(r)
+        eng.drain()
+    finally:
+        jax.profiler.stop_trace()
+    eng.close()
+    events = _events(tmp_path)
+    bucket_of = {st["batch"]: st["bucket"] for name, *_, st in events
+                 if name == "engine.flush"}
+    slabs = {b.name: (b, eng._slabs[b]) for b in eng._warmed}
+    d2h = [st for name, *_, st in events if name == "pipeline.d2h"]
+    assert spy.copies == len(d2h) == len(bucket_of) == eng.metrics.batches
+    for st in d2h:
+        bk, slab = slabs[bucket_of[st["batch"]]]
+        assert st["copies"] == 1
+        assert st["bytes"] == 4 * bk.batch * slab.width
 
 
 def test_executables_are_named_after_their_buckets():
