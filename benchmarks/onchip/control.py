@@ -101,23 +101,24 @@ def _control_fn(k: int, m2: int, eps: float, tol: float):
     return fn
 
 
-def control_answers(reqs, db_X, db_lam, *, k: int, eps: float,
+def control_answers(inputs, db_X, db_lam, *, k: int, eps: float,
                     tol: float, block: int = 32) -> list[Answer]:
-    """The control's answer to each request, by the KNN predictor.
-    Requests are padded to a common candidate count (utilities -1e30,
-    attributes 0), ``block`` at a time."""
+    """The control's answer to each request of plain ``inputs`` (as
+    ``reference.compare`` takes them), by the KNN predictor. Requests
+    are padded to a common candidate count (utilities -1e30, attributes
+    0), ``block`` at a time."""
     import jax.numpy as jnp
 
     X_db = jnp.asarray(db_X, jnp.float32)
     lam_db = jnp.asarray(db_lam, jnp.float32)
     y2 = jnp.sum(X_db * X_db, axis=1)
-    K = reqs[0].a.shape[0]
-    m1p = 1 << (max(r.u.shape[0] for r in reqs) - 1).bit_length()
-    m2 = max(r.m2 for r in reqs)
+    K = len(inputs[0]["b"])
+    m1p = 1 << (max(r["m1"] for r in inputs) - 1).bit_length()
+    m2 = max(r["m2"] for r in inputs)
     fn = _control_fn(k, m2, eps, tol)
     out = []
-    for s in range(0, len(reqs), block):
-        rb = reqs[s:s + block]
+    for s in range(0, len(inputs), block):
+        rb = inputs[s:s + block]
         n = len(rb)
         u = np.full((block, m1p), -1e30, np.float32)
         a = np.zeros((block, K, m1p), np.float32)
@@ -125,31 +126,32 @@ def control_answers(reqs, db_X, db_lam, *, k: int, eps: float,
         b = np.zeros((block, K), np.float32)
         X = np.zeros((block, X_db.shape[1]), np.float32)
         for i, r in enumerate(rb):
-            m1 = r.u.shape[0]
-            u[i, :m1] = r.u
-            a[i, :, :m1] = r.a
-            gamma[i, :r.m2] = r.gamma
-            b[i] = r.b
-            X[i] = r.X
+            m1 = r["m1"]
+            u[i, :m1] = r["u"]
+            a[i, :, :m1] = r["a"]
+            gamma[i, :r["m2"]] = r["gamma"]
+            b[i] = r["b"]
+            X[i] = r["X"]
         perm, util, expo, comp, lam = (np.asarray(v) for v in fn(
             X, X_db, y2, lam_db, u, a, b, gamma))
         for i, r in enumerate(rb[:n]):
-            out.append(Answer(perm=perm[i, :r.m2], utility=float(util[i]),
-                              exposure=expo[i], compliant=bool(comp[i]),
-                              lam=lam[i], rung=0))
+            out.append(Answer(perm=perm[i, :r["m2"]],
+                              utility=float(util[i]), exposure=expo[i],
+                              compliant=bool(comp[i]), lam=lam[i], rung=0))
     return out
 
 
-def readings(cfg, reqs, answers, db_X, db_lam, *, control: bool) -> dict:
-    """The comparison's numbers for the program's answers or, with
-    ``control``, for the control's answers to the same requests."""
+def readings(cfg, inputs, answers, db_X, db_lam, *, control: bool) -> dict:
+    """The comparison's numbers for the program's answers to requests of
+    plain ``inputs`` or, with ``control``, for the control's answers to
+    the same requests."""
     if control:
         answers = control_answers(
-            reqs, db_X, db_lam,
+            inputs, db_X, db_lam,
             k=int(cfg["knn_k"]), eps=float(cfg["eps"]),
             tol=float(cfg["audit_tol"]))
     db = reference.LamDB(db_X, db_lam)
-    return reference.compare(reqs, answers, db, k=int(cfg["knn_k"]),
+    return reference.compare(inputs, answers, db, k=int(cfg["knn_k"]),
                              eps=float(cfg["eps"]),
                              tol=float(cfg["audit_tol"]),
                              tie_rtol=float(cfg["knn_tie_rtol"]))
@@ -172,37 +174,38 @@ def main(argv=None) -> int:
     run.configure_jax(cfg)
     devices = run.tpu_devices(int(cell["chips"]))
     compiles = run.CompileCounter()
+    reqmod = layout.request_module(cfg)
     setup = deployment.build(cfg)
     pool = deployment.score_pool(setup, int(mix["pool"]))
     control_seeds = {int(s) for s in opts.control_seeds.split(",") if s}
     for seed in (int(s) for s in opts.seeds.split(",")):
-        engine, metrics, _ = run.start_engine(setup, pool, mix)
+        engine, metrics, _ = run.start_engine(setup, pool, mix, reqmod)
         plan = loadgen.make_plan(mix, seed, opts.seconds)
-        requests = deployment.make_requests(setup, pool, mix, plan.user,
-                                            plan.m1)
+        requests = reqmod.make(setup, pool, mix, plan.user, plan.m1)
         drive, _ = run.serve_plan(
             engine, metrics, requests, plan,
             budget_s=cfg["serve"]["budget_ms"] / 1e3, trace=False,
             compiles=compiles)
         engine.close()
         win = plan.window
-        pairs = [(q, a) for q, a in zip(requests[win], drive.answers[win])]
-        served = [i for i, (_, a) in enumerate(pairs)
-                  if isinstance(a, RankResult)]
+        answers = drive.answers[win]
+        served_idx = [i for i, a in enumerate(answers)
+                      if isinstance(a, RankResult)]
         rng = np.random.default_rng([seed, 1])
-        sample = run.check_sample(rng, pairs, served,
+        sample = run.check_sample(rng, plan.m1[win], served_idx,
                                   int(cfg["check_sample"]))
-        reqs = [pairs[i][0] for i in sample]
-        answers = [pairs[i][1] for i in sample]
+        window = requests[win]
+        inputs = reqmod.inputs(setup, pool, [window[i] for i in sample])
+        sampled = [answers[i] for i in sample]
         sides = [False] + ([True] if seed in control_seeds else [])
         for control in sides:
             t = time.perf_counter()
-            r = readings(cfg, reqs, answers, setup.X_db, setup.lam_db,
+            r = readings(cfg, inputs, sampled, setup.X_db, setup.lam_db,
                          control=control)
             print(json.dumps({
                 "workload": opts.workload, "seed": seed,
                 "side": "control" if control else "program",
-                "attempted": len(pairs), "served": len(served),
+                "attempted": len(window), "served": len(served_idx),
                 **r, "seconds": time.perf_counter() - t,
                 "device": {"platform": devices[0].platform,
                            "kind": devices[0].device_kind,

@@ -270,38 +270,33 @@ def predictor_tag(setup: Setup, mix: dict) -> str:
     return setup.cfg["arch"]
 
 
-def make_requests(setup: Setup, pool: Pool, mix: dict, users, m1s,
-                  *, rid0: int = 0) -> list:
-    """One RankRequest per (user, m1): the user's first m1 candidates."""
-    from repro.serving import RankRequest
-
-    tag = predictor_tag(setup, mix)
-    m2 = setup.dep.m2
-    reqs = []
-    for i, (user, m1) in enumerate(zip(users, m1s)):
-        m1 = int(m1)
-        m2r = min(m2, m1)
-        reqs.append(RankRequest(
-            rid=rid0 + i, u=pool.U[user, :m1], a=pool.topics[:, :m1],
-            b=pool.b, m2=m2r, X=pool.X[user], tag=tag,
-            gamma=pool.gamma[:m2r]))
-    return reqs
-
-
 def make_engine(setup: Setup, metrics=None):
-    """The serve driver's engine for this configuration."""
+    """``repro.launch.serve.make_engine``'s engine for this
+    configuration; the ``dist`` executor gets a (data 1, model chips)
+    mesh over the first ``chips`` devices, the axes
+    ``rank_input_shardings`` splits candidates over."""
+    import jax
+
     from repro.launch import serve
 
-    eng = serve.make_engine(setup.args, setup.dep,
-                            executor=setup.cfg["serve"]["executor"])
+    executor = setup.cfg["serve"]["executor"]
+    mesh = None
+    if executor == "dist":
+        chips = int(setup.cfg["chips"])
+        mesh = jax.make_mesh((1, chips), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                             devices=jax.devices()[:chips])
+    eng = serve.make_engine(setup.args, setup.dep, executor=executor,
+                            mesh=mesh)
     if metrics is not None:
         eng.metrics = metrics
     return eng
 
 
-def warm_sample(setup: Setup, pool: Pool, mix: dict) -> list:
-    """One request per bucket the mix can reach: every power-of-two m1
-    corner between the mix's bounds, and the bounds themselves."""
+def warm_m1s(mix: dict) -> list[int]:
+    """One candidate count per bucket the mix can reach: every
+    power-of-two m1 corner between the mix's bounds, and the bounds
+    themselves."""
     lo, hi = (int(v) for v in mix["m1"])
     m1s = {lo, hi}
     p = 1
@@ -309,5 +304,4 @@ def warm_sample(setup: Setup, pool: Pool, mix: dict) -> list:
         if p >= lo:
             m1s.add(p)
         p *= 2
-    m1s = sorted(m1s)
-    return make_requests(setup, pool, mix, [0] * len(m1s), m1s, rid0=-len(m1s))
+    return sorted(m1s)

@@ -5,14 +5,29 @@ work count is a file of its own, named after the entry in
 ``BENCHMARK.json`` that uses it:
 
   configs/<config>.json    sizes, serve-driver arguments, chips, the
-                           correctness limits of the deployment
+                           correctness limits of the deployment; two
+                           optional keys: ``"requests"`` names its
+                           requests/ module (``scored`` without it),
+                           ``"step"`` lists the work/ counts summed into
+                           its whole step (work/step.py's ``ROUTES``
+                           without it)
   traffic/<mix>.json       arrival process, rate, m1 range, predictor
                            tag, pool size
+  requests/<name>.py       ``make(setup, pool, mix, users, m1s, *,
+                           rid0)``: the window's and the warm-up's
+                           RankRequests; ``inputs(setup, pool, reqs)``:
+                           each request's plain inputs for the reference
+                           (u, a, b, gamma, X, m2, m1)
   metrics/<metric>.py      ``read(rec) -> float | None``: one number
                            from the run's record (and reduced trace)
   work/<kernel>.py         ``count(shape) -> {"flops", "bytes"}``: the
                            algorithm's work per batch from bucket shapes
+                           and the configuration's ``widths``
   peaks.json               per-``device_kind`` peaks with their source
+
+A configuration on more than one chip serves through the ``dist``
+executor (candidates split over the chips, top-m2 merged across them);
+chips are 1 or 4.
 
 A later change adds a cell by adding files and entries; nothing here
 lists them.
@@ -52,11 +67,21 @@ def _json(path: Path, what: str) -> dict:
         return json.load(f)
 
 
+CHIPS = (1, 4)
+
+
 def load_config(name: str, here: Path = HERE) -> dict:
     cfg = _json(Path(here) / "configs" / f"{name}.json", f"config {name!r}")
     if cfg.get("name") != name:
         raise LayoutError(f"configs/{name}.json names itself "
                           f"{cfg.get('name')!r}")
+    if cfg.get("chips") not in CHIPS:
+        raise LayoutError(f"configs/{name}.json: chips {cfg.get('chips')!r}"
+                          f" (have {CHIPS})")
+    if cfg["chips"] > 1 and cfg["serve"]["executor"] != "dist":
+        raise LayoutError(f"configs/{name}.json: {cfg['chips']} chips need "
+                          f"the dist executor, not "
+                          f"{cfg['serve']['executor']!r}")
     return cfg
 
 
@@ -97,6 +122,34 @@ def work_module(name: str, here: Path = HERE):
 def work_counter(name: str, here: Path = HERE):
     """``work/<name>.py``'s ``count`` function."""
     return work_module(name, here).count
+
+
+def step_counter(cfg: dict, here: Path = HERE):
+    """The whole step's ``count``: the sum of the work counts the
+    configuration's ``"step"`` lists, or work/step.py's."""
+    names = cfg.get("step")
+    if names is None:
+        return work_counter("step", here)
+    counts = [work_counter(n, here) for n in names]
+
+    def count(shape: dict) -> dict:
+        ws = [c(shape) for c in counts]
+        return {"flops": sum(w["flops"] for w in ws),
+                "bytes": sum(w["bytes"] for w in ws)}
+
+    return count
+
+
+def request_module(cfg: dict, here: Path = HERE):
+    """``requests/<name>.py`` of the configuration's ``"requests"`` key,
+    ``scored`` without it; it has ``make`` and ``inputs``."""
+    name = cfg.get("requests", "scored")
+    mod = _module(Path(here) / "requests" / f"{name}.py",
+                  f"requests {name!r}")
+    for fn in ("make", "inputs"):
+        if not callable(getattr(mod, fn, None)):
+            raise LayoutError(f"requests/{name}.py has no {fn}()")
+    return mod
 
 
 def peaks_for(device_kind: str, here: Path = HERE) -> dict:

@@ -50,10 +50,12 @@ def least_time(work: dict, peaks: dict) -> float:
 
 
 def batch_shape(bucket, *, predictor: str, d: int, n_db: int, k: int,
-                k_pred: int) -> dict:
+                k_pred: int, widths: dict) -> dict:
+    """A batch's shape as the work/ counts take it: the bucket's sizes,
+    the λ-database's, and the configuration's published ``widths``."""
     return {"B": bucket.batch, "m1": bucket.m1, "m2": bucket.m2,
             "K": bucket.K, "d": d, "n_db": n_db, "k": k, "k_pred": k_pred,
-            "predictor": predictor}
+            "predictor": predictor, "widths": widths}
 
 
 def build_record(drive, plan, metrics: RecordingMetrics, *,

@@ -2,10 +2,11 @@
 decides ``correct``.
 
 Independent of the program: numpy in float64, nothing imported from
-``repro``. It takes the served requests' inputs (utilities, topic
-attributes, floors, slot discounts, covariates), the deployment's
-λ-database, and a sample of the database's offline users' LP inputs
-rebuilt from their fixed keys, and recomputes, layer by layer:
+``repro``. It takes the served requests' plain inputs (utilities, topic
+attributes, floors, slot discounts, covariates, as the configuration's
+requests/ module gives them), the deployment's λ-database, and a sample
+of the database's offline users' LP inputs rebuilt from their fixed
+keys, and recomputes, layer by layer:
 
   λ-database each sampled row holds its own offline user: its
             covariate is that user's, and its λ, ranking that user's
@@ -102,14 +103,13 @@ def top_m2(s: np.ndarray, m2: int) -> np.ndarray:
     return part[np.lexsort((part, -s[part]))]
 
 
-def shape_ok(req, ans) -> bool:
-    """The answer has the request's geometry and came from the KNN
-    predictor (rung 0)."""
-    m1, K = req.u.shape[0], req.a.shape[0]
+def shape_ok(m1: int, m2: int, K: int, ans) -> bool:
+    """The answer has its request's geometry (m1 candidates, m2 slots, K
+    floors) and came from the KNN predictor (rung 0)."""
     perm = np.asarray(ans.perm)
-    return (ans.rung == 0 and perm.shape == (req.m2,)
+    return (ans.rung == 0 and perm.shape == (m2,)
             and perm.min(initial=0) >= 0 and perm.max(initial=0) < m1
-            and np.unique(perm).size == req.m2
+            and np.unique(perm).size == m2
             and np.shape(ans.exposure) == (K,)
             and np.shape(ans.lam) == (K,)
             and np.isfinite(ans.utility)
@@ -117,41 +117,43 @@ def shape_ok(req, ans) -> bool:
             and bool(np.isfinite(ans.lam).all()))
 
 
-def compare(reqs, answers, db: LamDB, *, k: int, eps: float, tol: float,
+def compare(inputs, answers, db: LamDB, *, k: int, eps: float, tol: float,
             tie_rtol: float, unanswered: int = 0) -> dict:
     """The numbers of the module docstring but the database's, for
     served ``answers`` (each with perm, utility, exposure, compliant,
-    lam, rung) of ``reqs``. Rung 0 is the KNN predictor."""
+    lam, rung) of requests with plain ``inputs`` (each a dict of u (m1,),
+    a (K, m1), b (K,), gamma (m2,), X (d,), m2, m1). Rung 0 is the KNN
+    predictor."""
     nums = dict.fromkeys(NUMBERS, 0.0)
     nums["unanswered"] = float(unanswered)
     counts = {"compared": 0, "knn_ties": 0, "perm_differs": 0,
               "compliant_borderline": 0}
     good = []
-    for req, ans in zip(reqs, answers):
-        if shape_ok(req, ans):
-            good.append((req, ans))
+    for inp, ans in zip(inputs, answers):
+        if shape_ok(inp["m1"], inp["m2"], len(inp["b"]), ans):
+            good.append((inp, ans))
         else:
             nums["shape_errors"] += 1
     if not good:
         return {"numbers": nums, "counts": counts}
-    X = np.stack([np.asarray(req.X) for req, _ in good])
+    X = np.stack([np.asarray(inp["X"]) for inp, _ in good])
     lam_ref, tie = knn_lambda(db, X, k=k, tie_rtol=tie_rtol)
     counts["knn_ties"] = int(tie.sum())
-    for i, (req, ans) in enumerate(good):
+    for i, (inp, ans) in enumerate(good):
         counts["compared"] += 1
-        K = req.a.shape[0]
+        K = len(inp["b"])
         lam_p = np.asarray(ans.lam, np.float64)
         if not tie[i]:
             ref = lam_ref[i, :K]
             err = (np.abs(lam_p - ref).max()
                    / max(np.abs(ref).max() + db.scale, 1e-30))
             nums["lam_err"] = max(nums["lam_err"], float(err))
-        u = np.asarray(req.u, np.float64)
-        a = np.asarray(req.a, np.float64)
-        gamma = np.asarray(req.gamma, np.float64)
+        u = np.asarray(inp["u"], np.float64)
+        a = np.asarray(inp["a"], np.float64)
+        gamma = np.asarray(inp["gamma"], np.float64)
         perm = np.asarray(ans.perm)
         s = u + (1.0 + eps) * ((lam_p if tie[i] else lam_ref[i, :K]) @ a)
-        perm_ref = top_m2(s, req.m2)
+        perm_ref = top_m2(s, inp["m2"])
         gap = 0.0
         if not np.array_equal(perm, perm_ref):
             counts["perm_differs"] += 1
@@ -162,7 +164,7 @@ def compare(reqs, answers, db: LamDB, *, k: int, eps: float, tol: float,
         err_e = np.abs(np.asarray(ans.exposure) - expo).max() / gamma.sum()
         nums["page_err"] = max(nums["page_err"], float(gap), float(err_u),
                                float(err_e))
-        b = np.asarray(req.b, np.float64)
+        b = np.asarray(inp["b"], np.float64)
         margin = np.abs(expo - (b - tol)).min()
         if margin <= 1e-5 * gamma.sum():
             counts["compliant_borderline"] += 1

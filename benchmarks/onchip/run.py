@@ -127,8 +127,9 @@ def device_dict(devices, chips: int) -> dict:
             "count": len(devices), "memory_peak_bytes": peak}
 
 
-def check_sample(rng, answers, served_idx, n: int):
-    """A seeded sample of the answered window requests, with the one of
+def check_sample(rng, m1s, served_idx, n: int):
+    """A seeded sample of the answered window requests (``m1s`` each
+    window request's candidate count, from the plan), with the one of
     most candidates always in it."""
     import numpy as np
 
@@ -136,19 +137,33 @@ def check_sample(rng, answers, served_idx, n: int):
     if served_idx.size <= n:
         return served_idx
     pick = rng.choice(served_idx, n - 1, replace=False)
-    longest = max(served_idx, key=lambda i: answers[i][0].u.shape[0])
+    longest = max(served_idx, key=lambda i: m1s[i])
     return np.unique(np.append(pick, longest))
 
 
-def start_engine(setup, pool, mix):
+def shape_errors(cfg: dict, m1s, answers, served_idx) -> int:
+    """Served answers whose page breaks their request's geometry: m1
+    from the plan, m2 and K from the configuration."""
+    from benchmarks.onchip import reference
+
+    m2, K = int(cfg["m2"]), int(cfg["K"])
+    return sum(1 for i in served_idx
+               if not reference.shape_ok(int(m1s[i]), min(m2, int(m1s[i])),
+                                         K, answers[i]))
+
+
+def start_engine(setup, pool, mix, reqmod):
     """The configuration's engine, warmed up on the mix's buckets (and
-    their degradation rungs): (engine, metrics, warmup report)."""
+    their degradation rungs) with the requests module ``reqmod``:
+    (engine, metrics, warmup report)."""
     from benchmarks.onchip import deployment, record
 
     metrics = record.RecordingMetrics()
     engine = deployment.make_engine(setup, metrics)
+    m1s = deployment.warm_m1s(mix)
     t = time.perf_counter()
-    warm = engine.warmup(deployment.warm_sample(setup, pool, mix))
+    warm = engine.warmup(reqmod.make(setup, pool, mix, [0] * len(m1s), m1s,
+                                     rid0=-len(m1s)))
     setup.seconds["warmup"] = time.perf_counter() - t
     return engine, metrics, warm
 
@@ -221,11 +236,13 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
         peaks = layout.peaks_for(devices[0].device_kind, here)
     srv = cfg["serve"]
 
+    reqmod = layout.request_module(cfg, here)
+    step_count = layout.step_counter(cfg, here)
     setup = deployment.build(cfg, cache=cache or deployment.CACHE)
     pool = deployment.score_pool(setup, int(mix["pool"]))
-    engine, metrics, warm = start_engine(setup, pool, mix)
+    engine, metrics, warm = start_engine(setup, pool, mix, reqmod)
     plan = loadgen.make_plan(mix, seed, seconds)
-    requests = deployment.make_requests(setup, pool, mix, plan.user, plan.m1)
+    requests = reqmod.make(setup, pool, mix, plan.user, plan.m1)
     log(f"setup: offline cache {'hit' if setup.offline_hit else 'miss'} "
         f"seconds {json.dumps(setup.seconds)} buckets {warm['buckets']} "
         f"requests {len(requests)} (lead-in {plan.n_lead}) rate "
@@ -251,11 +268,12 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
         return record.batch_shape(bucket, predictor=pred, d=int(d_cov),
                                   n_db=int(n_db),
                                   k=int(cfg["knn_k"]),
-                                  k_pred=K_pred)
+                                  k_pred=K_pred, widths=cfg["widths"])
 
     routes = layout.work_module("step", here).ROUTES
     counters = {name: layout.work_counter(name, here)
-                for name in ["step", *kernel_names(here)]}
+                for name in kernel_names(here)}
+    counters["step"] = step_count
     rec = record.build_record(
         drive, plan, metrics, t_open=t_open, t_close=t_close,
         budget_ms=srv["budget_ms"], setup_s=setup_s, chips=chips,
@@ -279,32 +297,36 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
         f"max_s {max((p for _, p in pauses), default=0.0):.4f} gen2 "
         f"{sum(1 for g, _ in pauses if g == 2)}")
 
-    # off the clock: free the engine, rebuild a sample of the offline
-    # users' LPs on the backbone, free it, then compare
+    # off the clock: free the engine; take the sampled requests' inputs
+    # and rebuild a sample of the offline users' LPs on the backbone,
+    # free it, then compare
     win = plan.window
-    answers = list(zip(requests[win], drive.answers[win]))
-    del engine, metrics, drive
+    answers = drive.answers[win]
+    m1s = plan.m1[win]
+    reqs = requests[win]
+    del engine, metrics, drive, requests
     gc.collect()
     t = time.perf_counter()
+    served_idx = [i for i, a in enumerate(answers)
+                  if isinstance(a, RankResult)]
+    rng = np.random.default_rng([seed, 1])
+    sample = check_sample(rng, m1s, served_idx, int(cfg["check_sample"]))
+    inputs = reqmod.inputs(setup, pool, [reqs[i] for i in sample])
     db_rows, db_U, db_X = deployment.offline_rows(
         setup, np.random.default_rng([seed, 2]), int(cfg["db_sample"]))
     gamma_off, topics_off, b_off = deployment.offline_surface(cfg)
     setup.dep = None
+    del reqs
     gc.collect()
-    served_idx = [i for i, (_, a) in enumerate(answers)
-                  if isinstance(a, RankResult)]
-    shape_errors = sum(1 for i in served_idx
-                       if not reference.shape_ok(*answers[i]))
-    rng = np.random.default_rng([seed, 1])
-    sample = check_sample(rng, answers, served_idx, int(cfg["check_sample"]))
     db = reference.LamDB(setup.X_db, setup.lam_db)
     lim = cfg["limits"]
     cmp = reference.compare(
-        [answers[i][0] for i in sample], [answers[i][1] for i in sample],
+        inputs, [answers[i] for i in sample],
         db, k=int(cfg["knn_k"]), eps=float(cfg["eps"]),
         tol=float(cfg["audit_tol"]), tie_rtol=float(cfg["knn_tie_rtol"]),
         unanswered=rec["failed"])
-    cmp["numbers"]["shape_errors"] = float(shape_errors)
+    cmp["numbers"]["shape_errors"] = float(
+        shape_errors(cfg, m1s, answers, served_idx))
     dbc = reference.check_db(db, db_rows, db_U, db_X, gamma=gamma_off,
                              topics=topics_off, b=b_off,
                              eps=float(cfg["eps"]),

@@ -37,17 +37,19 @@ from benchmarks.onchip import layout, run  # noqa: E402
 
 
 def rate_point(setup, pool, mix, rate: float, *, seed: int, seconds: float,
-               compiles) -> dict:
+               compiles, reqmod) -> dict:
+    """One rate of the ladder, served with the requests module
+    ``reqmod``."""
     import numpy as np
 
-    from benchmarks.onchip import deployment, loadgen
+    from benchmarks.onchip import loadgen
     from repro.serving import RankResult, Shed
 
-    engine, metrics, _ = run.start_engine(setup, pool, mix)
+    engine, metrics, _ = run.start_engine(setup, pool, mix, reqmod)
     plan = loadgen.make_plan(mix, seed, seconds, rate=rate)
-    requests = deployment.make_requests(setup, pool, mix, plan.user, plan.m1)
+    reqs = reqmod.make(setup, pool, mix, plan.user, plan.m1)
     budget_ms = setup.cfg["serve"]["budget_ms"]
-    drive, _ = run.serve_plan(engine, metrics, requests, plan,
+    drive, _ = run.serve_plan(engine, metrics, reqs, plan,
                               budget_s=budget_ms / 1e3, trace=False,
                               compiles=compiles)
     engine.close()
@@ -101,13 +103,15 @@ def main(argv=None) -> int:
 
     from benchmarks.onchip import deployment
 
+    reqmod = layout.request_module(cfg)
     setup = deployment.build(cfg)
     pool = deployment.score_pool(setup, int(mix["pool"]))
     log(f"setup {time.perf_counter() - T_PROC:.1f} s")
     points = []
     for rate in (float(r) for r in opts.rates.split(",")):
         p = rate_point(setup, pool, mix, rate, seed=opts.seed,
-                       seconds=opts.seconds, compiles=compiles)
+                       seconds=opts.seconds, compiles=compiles,
+                       reqmod=reqmod)
         points.append(p)
         print(json.dumps({"workload": opts.workload, **p}), flush=True)
     print(json.dumps({"workload": opts.workload, "knee_rps": knee(points),

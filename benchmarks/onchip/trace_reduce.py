@@ -14,6 +14,11 @@ Per device plane (``/device:TPU:<n>``), from its ops line:
                 name (the stems of ``work/``); kernel_n their count
   module_s      device seconds of whole executables (the modules line),
                 and ``modules`` their count
+  collective_s  device seconds of the collective ops (all-gather,
+                all-reduce, collective-permute, all-to-all,
+                reduce-scatter, the start and done halves of their
+                asynchronous forms, and fusions XLA names after them),
+                by opcode or instruction name
 
 Across devices: the means of these over the devices that ran anything,
 the window's length, and the longest idle gaps of device 0, each named
@@ -35,6 +40,8 @@ MODULE_LINES = ("XLA Modules",)
 HOST_SPAN = "bench."
 HOST_EVENT_MIN_NS = 1_000_000
 WINDOW_SPAN = "bench.window"
+COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
+               "all-to-all", "reduce-scatter")
 
 
 def find_xplane(trace_dir) -> Path:
@@ -92,6 +99,14 @@ def _kernel(text: str, kernels) -> str | None:
     return None
 
 
+def is_collective(lhs: str, opcode: str) -> bool:
+    """An op (``parse_op``'s instruction name and opcode) is a
+    collective: either starts with one (``all-gather-start``,
+    ``%all-reduce.3``, ``%all-gather-fusion.1``, ...)."""
+    return (opcode.startswith(COLLECTIVES)
+            or lhs.lstrip("%").startswith(COLLECTIVES))
+
+
 def _most_overlap(spans, s, e) -> str | None:
     best, name = 0, None
     for hs, he, hn in spans:
@@ -136,7 +151,7 @@ def reduce_profile(pd, kernels=(), gaps: int = 10) -> dict:
     for dev_id, plane in sorted(devices.items()):
         ivs, op_s = [], defaultdict(float)
         kernel_s, kernel_n = defaultdict(float), defaultdict(int)
-        module_s, modules = 0.0, 0
+        module_s, modules, collective_s = 0.0, 0, 0.0
         for line in plane.lines:
             if line.name in OPS_LINES:
                 for ev in line.events:
@@ -147,7 +162,10 @@ def reduce_profile(pd, kernels=(), gaps: int = 10) -> dict:
                     ivs.append((s, e))
                     d = (e - s) / 1e9
                     op_s[short_name(ev.name)] += d
-                    k = _kernel(parse_op(ev.name)[0], kernels)
+                    lhs, _, opcode = parse_op(ev.name)
+                    if is_collective(lhs, opcode):
+                        collective_s += d
+                    k = _kernel(lhs, kernels)
                     if k is not None:
                         kernel_s[k] += d
                         kernel_n[k] += 1
@@ -164,7 +182,7 @@ def reduce_profile(pd, kernels=(), gaps: int = 10) -> dict:
                            "kernel_s": dict(kernel_s),
                            "kernel_n": dict(kernel_n),
                            "module_s": module_s, "modules": modules,
-                           "merged": merged}
+                           "collective_s": collective_s, "merged": merged}
     active = [d for d in per_dev.values() if d["busy_s"] > 0]
     n = max(len(active), 1)
     ops = defaultdict(float)
@@ -201,6 +219,7 @@ def reduce_profile(pd, kernels=(), gaps: int = 10) -> dict:
         "kernel_n": dict(kern_n),
         "module_s": sum(d["module_s"] for d in active) / n,
         "modules": sum(d["modules"] for d in active) / n,
+        "collective_s": sum(d["collective_s"] for d in active) / n,
         "idle_gaps": idle_gaps,
     }
 

@@ -13,6 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [p for p in (str(ROOT), str(ROOT / "src")) if p not in sys.path]
 
 import copy
+import shutil
 
 import jax
 import numpy as np
@@ -22,6 +23,7 @@ from benchmarks.onchip import control, deployment, layout, loadgen, run
 from repro.serving import RankResult
 
 CELL = {"name": "sasrec-feed.steady", "chips": 1}
+DATA = Path(__file__).parent / "data"
 
 
 def tiny_config(name="sasrec-feed"):
@@ -177,20 +179,99 @@ def test_an_answer_that_never_comes_is_not_correct(cache, compiles,
     assert res["checks"]["unanswered"]["value"] == res["failed"]
 
 
+def _tree_with(tmp_path, files):
+    """A copy of the benchmark's directory with ``files`` (relative path
+    -> text) added; (its path, the bytes of what was there before)."""
+    here = tmp_path / "onchip"
+    shutil.copytree(layout.HERE, here,
+                    ignore=shutil.ignore_patterns(".cache", "__pycache__"))
+    before = {p: p.read_bytes() for p in here.rglob("*") if p.is_file()}
+    for rel, text in files.items():
+        (here / rel).write_text(text)
+    return here, before
+
+
+def run_in(here, cfg, cache, compiles):
+    return run.run_cell(layout.load_benchmark(), CELL, cfg, tiny_mix(),
+                        seed=2**31 + 3, seconds=0.5, trace=False,
+                        devices=jax.devices(), cache=cache,
+                        compiles=compiles,
+                        peaks=layout.peaks_for("TPU v5 lite"),
+                        log=lambda msg: None, here=here)
+
+
+def test_a_configuration_brings_its_own_requests_and_step(
+        tmp_path, cache, compiles, monkeypatch):
+    """A later configuration (a backbone on the served path) adds its
+    requests module and its step's work counts as new files; the
+    harness runs it with no edit to a file that was there."""
+    from benchmarks.onchip import record
+
+    here, before = _tree_with(tmp_path, {
+        "requests/echo.py": (
+            "from benchmarks.onchip import layout\n"
+            "_scored = layout.request_module({})\n"
+            "make, inputs = _scored.make, _scored.inputs\n"),
+        "work/tower.py": (
+            "def count(shape):\n"
+            "    w = shape['widths']['embed_dim']\n"
+            "    return {'flops': 2 * shape['B'] * shape['m1'] * w,\n"
+            "            'bytes': 4 * w}\n")})
+    cfg = {**tiny_config(), "requests": "echo",
+           "step": ["knn_rank_audited", "tower"]}
+    recs = []
+    build = record.build_record
+
+    def keep(*a, **kw):
+        recs.append(build(*a, **kw))
+        return recs[-1]
+
+    monkeypatch.setattr(record, "build_record", keep)
+    res = run_in(here, cfg, cache, compiles)
+    assert res["correct"], res["checks"]
+    work = recs[0]["work"]
+    assert work["step"]["batches"] == work["knn_rank_audited"]["batches"] > 0
+    tower = work["step"]["flops"] - work["knn_rank_audited"]["flops"]
+    shapes = [b["bucket"] for b in recs[0]["batches"]]
+    assert tower == sum(2 * 32 * int(b.split("m1=")[1].split("/")[0]) * 8
+                        for b in shapes)
+    for p, data in before.items():       # nothing that was there changed
+        assert p.read_bytes() == data
+
+
+def test_the_reference_reads_the_requests_module_s_inputs(
+        tmp_path, cache, compiles):
+    """A module that plants an offset in the utilities it hands the
+    reference makes a sound run incorrect: the reference compares
+    against the module's inputs, not the request's arrays."""
+    here, _ = _tree_with(tmp_path, {
+        "requests/requests_offset_u.py":
+            (DATA / "requests_offset_u.py").read_text()})
+    cfg = {**tiny_config(), "requests": "requests_offset_u"}
+    res = run_in(here, cfg, cache, compiles)
+    assert not res["correct"]
+    c = res["checks"]["page_err"]
+    assert c["value"] > c["limit"]
+    assert res["checks"]["lam_err"]["value"] <= res["checks"]["lam_err"][
+        "limit"]
+
+
 def test_the_control_fails_the_limits(cache, compiles):
     cfg, mix = tiny_config(), tiny_mix()
+    reqmod = layout.request_module(cfg)
     setup = deployment.build(cfg, cache=cache)
     pool = deployment.score_pool(setup, int(mix["pool"]))
-    engine, metrics, _ = run.start_engine(setup, pool, mix)
+    engine, metrics, _ = run.start_engine(setup, pool, mix, reqmod)
     plan = loadgen.make_plan(mix, 5, 0.5)
-    reqs = deployment.make_requests(setup, pool, mix, plan.user, plan.m1)
+    reqs = reqmod.make(setup, pool, mix, plan.user, plan.m1)
     drive, _ = run.serve_plan(engine, metrics, reqs, plan, budget_s=0.05,
                               trace=False, compiles=compiles)
     engine.close()
     pairs = [(q, a) for q, a in zip(reqs[plan.window],
                                     drive.answers[plan.window])
              if isinstance(a, RankResult)]
-    q, a = [p[0] for p in pairs], [p[1] for p in pairs]
+    q = reqmod.inputs(setup, pool, [p[0] for p in pairs])
+    a = [p[1] for p in pairs]
     limits = layout.load_config("sasrec-feed")["limits"]
     program = control.readings(cfg, q, a, setup.X_db, setup.lam_db,
                                control=False)

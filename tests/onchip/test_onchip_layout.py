@@ -91,6 +91,30 @@ def test_a_missing_file_is_an_error(tmp_path):
         layout.metric_reader("no_such_metric")
 
 
+@pytest.mark.parametrize("key,value,find", [
+    ("requests", "no_such_requests", layout.request_module),
+    ("step", ["knn_rank_audited", "no_such_count"], layout.step_counter)])
+def test_an_absent_request_module_or_step_count_is_an_error(key, value,
+                                                            find):
+    cfg = {**layout.load_config("mind-retrieval"), key: value}
+    with pytest.raises(layout.LayoutError):
+        find(cfg)
+
+
+def test_more_than_one_chip_needs_the_dist_executor(tmp_path):
+    here = tmp_path / "onchip"
+    (here / "configs").mkdir(parents=True)
+    cfg = layout.load_config("mind-retrieval-4chip")
+    assert (cfg["chips"], cfg["serve"]["executor"]) == (4, "dist")
+    for name, chips, executor in (("four-fused", 4, "fused"),
+                                  ("two-dist", 2, "dist")):
+        bad = {**cfg, "name": name, "chips": chips,
+               "serve": {**cfg["serve"], "executor": executor}}
+        (here / "configs" / f"{name}.json").write_text(json.dumps(bad))
+        with pytest.raises(layout.LayoutError):
+            layout.load_config(name, here)
+
+
 def test_peaks_are_keyed_by_device_kind():
     v5e = layout.peaks_for("TPU v5 lite")
     assert v5e["bf16_flops_per_s"] == 197e12

@@ -100,6 +100,36 @@ def test_hand_made_profile():
         ["bench.wait > np.asarray(jax.Array)", pytest.approx(200e-9)]]
 
 
+def test_collective_time_of_the_hand_made_profile():
+    from benchmarks.onchip import layout
+
+    p = _profile()
+    # device 1 also runs the halves of an asynchronous all-gather and a
+    # fusion named after one; a copy that merely reads a collective's
+    # output is no collective
+    p.planes[2].lines[0].events += [
+        _ev("%all-gather-start.1 = (f32[32,64]{1,0}, f32[128,64]{1,0}) "
+            "all-gather-start(f32[32,64]{1,0} %v)", 1100, 40),
+        _ev("%all-gather-done.1 = f32[128,64]{1,0} "
+            "all-gather-done((f32[32,64]{1,0}, f32[128,64]{1,0}) "
+            "%all-gather-start.1)", 1200, 60),
+        _ev("%all-gather-fusion.2 = f32[8]{0} fusion(f32[8] %w)", 1600, 20),
+        _ev("%copy.9 = f32[128,64]{1,0} copy(f32[128,64]{1,0} "
+            "%all-gather-done.1)", 1700, 30)]
+    r = trace_reduce.reduce_profile(p, KERNELS)
+    # all-reduce 100 ns on device 0, 40 + 60 + 20 ns on device 1
+    assert r["collective_s"] == pytest.approx((100e-9 + 120e-9) / 2)
+    read = layout.metric_reader("collective_us")
+    assert read({"trace": r}) == pytest.approx(1e6 * 110e-9 / 1.0)
+
+
+def test_a_trace_without_collectives_has_no_collective_time(chip_trace):
+    from benchmarks.onchip import layout
+
+    assert chip_trace["collective_s"] == 0.0
+    assert layout.metric_reader("collective_us")({"trace": chip_trace}) is None
+
+
 def test_a_trace_without_the_window_is_refused():
     p = _profile()
     p.planes[0].lines[0].events.pop(0)

@@ -6,6 +6,8 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [p for p in (str(ROOT), str(ROOT / "src")) if p not in sys.path]
 
+import shutil
+
 import pytest
 
 from benchmarks.onchip import layout
@@ -36,3 +38,22 @@ def test_step_follows_the_predictor_not_the_executor():
             == layout.work_counter("knn_rank_audited")(shape))
     with pytest.raises(KeyError):
         layout.work_counter("step")({**SHAPE, "predictor": "mean"})
+
+
+def test_step_sums_the_counts_the_configuration_lists(tmp_path):
+    shape = {**SHAPE, "predictor": "knn", "widths": {"embed_dim": 10}}
+    here = tmp_path / "onchip"
+    shutil.copytree(layout.HERE / "work", here / "work",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (here / "work" / "tower.py").write_text(
+        "def count(shape):\n"
+        "    w = shape['widths']['embed_dim']\n"
+        "    return {'flops': 2 * shape['B'] * shape['m1'] * w,\n"
+        "            'bytes': 4 * w}\n")
+    knn = layout.work_counter("knn_rank_audited")(shape)
+    both = layout.step_counter({"step": ["knn_rank_audited", "tower"]},
+                               here)(shape)
+    assert both == {"flops": knn["flops"] + 2 * 32 * 1024 * 10,
+                    "bytes": knn["bytes"] + 40}
+    # without the key, work/step.py's routes: the predictor's kernel
+    assert layout.step_counter({}, here)(shape) == knn
